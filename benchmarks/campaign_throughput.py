@@ -105,6 +105,23 @@ def _warm(iterations: int, propose_k: int, n_sample: int) -> None:
     _sharing_latency.cache_clear()
 
 
+def _require_cpu_mesh() -> None:
+    """Exit non-zero unless the forced virtual CPU devices are here.
+
+    ``--xla_force_host_platform_device_count`` applies to the CPU backend
+    only; on a TPU host the workers would see the chip instead, so this
+    CPU rehearsal refuses to run there.
+    """
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "cpu" or len(devs) < N_DEVICES:
+        raise SystemExit(
+            f"campaign_throughput needs {N_DEVICES} forced CPU devices; "
+            f"found {len(devs)} {devs[0].platform} device(s).  On a TPU "
+            f"host, `python chip_smoke.py --chips 4` runs the sharded "
+            f"campaign on the chips")
+
+
 # ---------------------------------------------------------------------------
 # workers (one subprocess each; --xla_force_host_platform_device_count set
 # by the orchestrator before jax ever imports)
@@ -112,8 +129,7 @@ def _warm(iterations: int, propose_k: int, n_sample: int) -> None:
 
 
 def worker_single(repeats, iterations, propose_k, n_sample) -> None:
-    import jax
-    assert len(jax.devices()) >= N_DEVICES
+    _require_cpu_mesh()
     from repro.core.dse import WorkloadEvaluator, run_dse
     from repro.core.surrogates import make_strategy
     from repro.engine.pareto import ParetoFront
@@ -140,8 +156,7 @@ def worker_single(repeats, iterations, propose_k, n_sample) -> None:
 
 def worker_sharded(repeats, iterations, propose_k, n_sample,
                    workdir: str) -> None:
-    import jax
-    assert len(jax.devices()) >= N_DEVICES
+    _require_cpu_mesh()
     from repro.engine import PersistentEvalCache, ShardedCampaign
     from repro.obs.trace import Tracer
 
@@ -172,8 +187,7 @@ def worker_sharded(repeats, iterations, propose_k, n_sample,
 def worker_kill(iterations, propose_k, n_sample, workdir: str,
                 die_after: int) -> None:
     """Run one tenant sharded, then die mid-campaign without cleanup."""
-    import jax
-    assert len(jax.devices()) >= N_DEVICES
+    _require_cpu_mesh()
     from repro.engine import PersistentEvalCache, ShardedCampaign
 
     class DyingCampaign(ShardedCampaign):
@@ -196,8 +210,7 @@ def worker_kill(iterations, propose_k, n_sample, workdir: str,
 
 
 def worker_resume(iterations, propose_k, n_sample, workdir: str) -> None:
-    import jax
-    assert len(jax.devices()) >= N_DEVICES
+    _require_cpu_mesh()
     from repro.engine import PersistentEvalCache, ShardedCampaign
 
     _warm(iterations, propose_k, n_sample)

@@ -117,7 +117,7 @@ def _single_16x16(iters: int, seed: int = 0) -> dict:
     from benchmarks.scheduler_throughput import CHUNK, EPJ, FLIT_BW, FREQ, \
         fig12_problem
     from repro.core.scheduler import solve_ilp_ls
-    from repro.engine.scheduler_opt import _USE_PALLAS
+    from repro.runtime import native_kernels
 
     noc, sets = fig12_problem(16, 4)
     chunks = [CHUNK] * len(sets)
@@ -135,7 +135,7 @@ def _single_16x16(iters: int, seed: int = 0) -> dict:
     assert scan.max_link_bytes <= loop.max_link_bytes + 1e-9
     return {
         "table": "pipeline", "case": "single_16x16",
-        "path": "pallas-stream" if _USE_PALLAS else "jnp-dense",
+        "path": "pallas-stream" if native_kernels() else "jnp-dense",
         "scan_s": t_scan, "loop_s": t_loop, "speedup": t_loop / t_scan,
     }
 

@@ -64,7 +64,7 @@ SMOKE_KW = dict(batch=8, single_iters=400, batch_iters=200, min_speedup=1.5)
 def run(seed: int = 0, batch: int = 24, single_iters: int = 1200,
         batch_iters: int = 400, min_speedup: float = 5.0,
         assert_5x: bool = True, min_single16: float = 1.0) -> list[dict]:
-    from repro.engine.scheduler_opt import _USE_PALLAS
+    from repro.runtime import native_kernels
 
     rows: list[dict] = []
 
@@ -88,7 +88,7 @@ def run(seed: int = 0, batch: int = 24, single_iters: int = 1200,
             f"loop {loop.max_link_bytes} — the engine search regressed")
         rows.append({
             "table": "scheduler", "case": f"single_{dim}x{dim}",
-            "path": "pallas-stream" if _USE_PALLAS else "jnp-dense",
+            "path": "pallas-stream" if native_kernels() else "jnp-dense",
             "scan_s": t_scan, "loop_s": t_loop,
             "speedup": t_loop / t_scan,
             "scan_obj": scan.max_link_bytes, "loop_obj": loop.max_link_bytes,

@@ -58,7 +58,8 @@ def _warm_buckets(tuner, *, n_min: int, n_max: int, n_sample: int,
     dataset size is a fresh shape, so its per-iteration retraces are the
     measured pathology and stay inside the timed region.
     """
-    from repro.core.tuner import _DKL_OPT, _FILTER_OPT, _USE_PALLAS
+    from repro.core.tuner import _DKL_OPT, _FILTER_OPT
+    from repro.runtime import native_kernels
     from repro.engine.tuner_train import (fit_dkl, fit_filter,
                                           score_candidates)
     rng = np.random.default_rng(0)
@@ -80,7 +81,7 @@ def _warm_buckets(tuner, *, n_min: int, n_max: int, n_sample: int,
         fit_dkl(copy(sg.params), copy(sg.opt_state), x, y, mask,
                 opt=_DKL_OPT, steps=dkl_steps)
         score_candidates(sg.params, x, y, mask, xq, ok, tuner.beta,
-                         use_pallas=_USE_PALLAS)
+                         use_pallas=native_kernels())
 
 
 def _drive(backend: str, cfgs, areas, costs, *, iterations: int, n0: int,

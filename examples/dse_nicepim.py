@@ -25,7 +25,8 @@ import argparse
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
 from repro.core.dse import WorkloadEvaluator, run_dse
 from repro.core.mapper import mapper_cache_stats
@@ -34,6 +35,7 @@ from repro.core.workloads import bert_base, googlenet
 from repro.engine.cache import EvalCache
 from repro.engine.tuner_train import compiled_program_count
 from repro.obs.trace import Tracer
+from repro.runtime import configure_compile_cache
 
 
 def main() -> None:
@@ -54,6 +56,7 @@ def main() -> None:
                     help="write a Chrome-trace of the run here "
                          "(Perfetto / chrome://tracing)")
     args = ap.parse_args()
+    configure_compile_cache(ROOT)
 
     workloads = [googlenet(1, scale=4),
                  bert_base(1, seq=64, n_layers=2, n_heads=4)]

@@ -548,23 +548,12 @@ def _layer_candidates_batched(struct: _CandStruct, node_lat: np.ndarray
 
 import numpy as np
 
-_ON_TPU: bool | None = None
-
-
-def _on_tpu() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        try:
-            import jax
-            _ON_TPU = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - jax always present here
-            _ON_TPU = False
-    return _ON_TPU
-
 
 def _resolve_reduce(reduce: str) -> str:
+    # "auto" is NumPy on every backend: the DP runs in f64 (identical
+    # choices to the scalar reference), and Mosaic compiles no f64 kernel
     if reduce == "auto":
-        return "pallas" if _on_tpu() else "numpy"
+        return "numpy"
     if reduce not in ("numpy", "pallas"):
         raise ValueError(f"unknown DP reduce {reduce!r}; "
                          f"expected 'auto', 'numpy' or 'pallas'")
@@ -579,8 +568,9 @@ def minplus_convolve(tab: np.ndarray, best: np.ndarray, *,
     ``(cap, prefix-budget)`` split of the shared per-node DRAM budget is
     scored at once and reduced with a min + *first*-argmin over the prefix
     budget ``i`` — the exact first-strict-< winner of the old sequential
-    i-ascending update loop.  ``reduce`` picks vectorized NumPy or the Pallas
-    ``kernels.dse_eval.minplus_rows`` kernel (``interpret=True`` off-TPU).
+    i-ascending update loop.  ``reduce`` picks vectorized NumPy (``"auto"``)
+    or the Pallas ``kernels.dse_eval.minplus_rows`` kernel, which runs in
+    f64 and so only in interpret mode (the kernel's parity tests).
 
     Returns ``(out, arg)`` with ``arg[c] = -1`` where no feasible split
     exists (``out[c]`` stays ``inf``), matching the old loop's untouched
@@ -591,9 +581,9 @@ def minplus_convolve(tab: np.ndarray, best: np.ndarray, *,
     # rows[c, i] = best[c - i] for i <= c, inf otherwise (Toeplitz of best)
     rows = np.lib.stride_tricks.sliding_window_view(ext, u + 1)[:, ::-1]
     if _resolve_reduce(reduce) == "pallas":
-        from jax.experimental import enable_x64
         from ..kernels import dse_eval
-        with enable_x64():
+        from ..runtime import x64
+        with x64():
             mn, idx = dse_eval.minplus_rows(tab, np.ascontiguousarray(rows))
         mn = np.asarray(mn)
         idx = np.asarray(idx)
@@ -612,8 +602,9 @@ class RegionTable:
     ``(candidate, cap)`` cell is scored at once (``perf[cap - size] + perf_c``
     where feasible) and the min + first-argmin over candidates — the exact
     first-strict-< winner of the old per-candidate Python loop — runs either
-    in NumPy or in the Pallas ``kernels.dse_eval.argmin_rows`` reduction
-    (``reduce="pallas"``, the on-TPU default alongside ``tile_select``).
+    in NumPy (``reduce="auto"``) or in the Pallas
+    ``kernels.dse_eval.argmin_rows`` reduction (``reduce="pallas"``, f64, so
+    interpret mode only — the kernel's parity tests).
 
     Backtracking is array-based (O(layers x units) int16), replayed in
     reverse: at budget ``cap`` layer ``l`` chose candidate ``choice[l, eff]``
@@ -647,9 +638,9 @@ class RegionTable:
                 scores = np.where(
                     feas, perf[np.clip(left, 0, units)] + perfs[:, None], INF)
                 if reduce == "pallas":
-                    from jax.experimental import enable_x64
                     from ..kernels import dse_eval
-                    with enable_x64():
+                    from ..runtime import x64
+                    with x64():
                         mn, idx = dse_eval.argmin_rows(scores.T)
                     nperf = np.asarray(mn)
                     ci = np.asarray(idx)

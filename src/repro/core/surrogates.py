@@ -21,7 +21,6 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -29,11 +28,6 @@ from .hardware import (DEFAULT_CONSTRAINTS, HwConfig, PimConstraints,
                        configs_from_rows, normalize_params,
                        normalize_params_batch, sample_config_values,
                        sample_configs_batch, sample_space)
-
-# interpret-mode Pallas is slower than plain jnp off-TPU (same policy as the
-# tuner and the mapper's knapsack reduce)
-_USE_PALLAS = jax.default_backend() == "tpu"
-
 
 class _Base:
     def __init__(self, cons: PimConstraints = DEFAULT_CONSTRAINTS,
@@ -110,7 +104,8 @@ class GPSurrogate(_Base):
 
     ``backend="engine"`` (default) scores candidates through the shared
     masked-Cholesky / LCB primitives in :mod:`repro.engine.tuner_train`
-    (float64, pow2-padded — one jitted dispatch per candidate batch);
+    (float64, pow2-padded — one jitted dispatch per candidate batch; the
+    f32-only Pallas ``lcb_rows`` kernel is not used, on any backend);
     ``backend="numpy"`` is the original dense reference, kept for parity.
     """
 
@@ -148,8 +143,8 @@ class GPSurrogate(_Base):
         return mean - self.beta * np.sqrt(var)
 
     def _rank_engine(self, xq: np.ndarray) -> np.ndarray:
-        from jax.experimental import enable_x64
         from ..engine.tuner_train import pow2_bucket, score_candidates_raw
+        from ..runtime import x64
         x = np.array(self._x, np.float64)
         y = np.array(self._y, np.float64)
         n = len(y)
@@ -158,12 +153,11 @@ class GPSurrogate(_Base):
         yp = np.zeros((p,))
         mask = np.zeros((p,), bool)
         xp[:n], yp[:n], mask[:n] = x, y, True
-        with enable_x64():
+        with x64():
             scores = score_candidates_raw(
                 jnp.asarray(xp), jnp.asarray(yp), jnp.asarray(mask),
                 jnp.asarray(np.asarray(xq, np.float64)),
-                jnp.ones(len(xq), bool), self.beta,
-                use_pallas=_USE_PALLAS)
+                jnp.ones(len(xq), bool), self.beta)
         return np.asarray(scores)
 
     def propose(self, k: int = 8) -> list[HwConfig]:

@@ -39,6 +39,7 @@ import numpy as np
 from ..engine.tuner_train import (dkl_features, fit_dkl, fit_filter,
                                   mlp_forward, mlp_init, pad_dataset,
                                   rbf_cross, score_candidates)
+from ..runtime import native_kernels
 from ..training.optim import Adam
 from .hardware import HwConfig, PimConstraints, DEFAULT_CONSTRAINTS, \
     configs_from_rows, normalize_params, normalize_params_batch, \
@@ -49,11 +50,6 @@ from .hardware import HwConfig, PimConstraints, DEFAULT_CONSTRAINTS, \
 _init_mlp = mlp_init
 _mlp_forward = mlp_forward
 _features = dkl_features
-
-# the Pallas LCB kernel is the on-TPU default; off-TPU the pure-jnp scoring
-# path is faster than interpret-mode Pallas (same policy as the mapper's
-# knapsack reduce)
-_USE_PALLAS = jax.default_backend() == "tpu"
 
 
 def _check_backend(backend: str) -> str:
@@ -311,7 +307,7 @@ class DklSuggestionModel:
         ok = np.ones(len(xq), bool) if area_ok is None else area_ok
         return np.asarray(score_candidates(
             self.params, xp, yp, mask, jnp.asarray(xq, jnp.float32),
-            ok, self.beta, use_pallas=_USE_PALLAS))
+            ok, self.beta, use_pallas=native_kernels()))
 
     def rank(self, cfgs: list[HwConfig]) -> np.ndarray:
         """Scores (lower = better); LCB on the predicted cost."""

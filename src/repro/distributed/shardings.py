@@ -14,75 +14,29 @@ tensor dim is dropped (replicated) rather than failing to lower.
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# --------------------------------------------------------------------------
-# jax version compat: the ambient-mesh API (get_abstract_mesh / set_mesh /
-# AxisType) moved into jax.sharding in 0.5.x; on 0.4.x the same machinery
-# lives under jax._src.mesh.  Resolve whichever exists once at import.
-# --------------------------------------------------------------------------
-
-def _resolve_mesh_api():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    setm = getattr(jax.sharding, "set_mesh", None)
-    if get is None or setm is None:
-        try:
-            from jax._src import mesh as _jmesh
-            get = get or getattr(_jmesh, "get_abstract_mesh", None)
-            setm = setm or getattr(_jmesh, "set_mesh", None)
-        except ImportError:  # pragma: no cover - future jax reorganisation
-            pass
-    return get, setm
-
-
-_GET_ABSTRACT_MESH, _SET_MESH = _resolve_mesh_api()
-
-
 def get_abstract_mesh():
-    """The ambient abstract mesh, or None when unset/unsupported.
-
-    Normalizes the 0.4.x sentinel (an empty tuple) and meshes without axis
-    names to None so callers only need one "no ambient mesh" branch.
-    """
-    if _GET_ABSTRACT_MESH is None:
-        return None
-    mesh = _GET_ABSTRACT_MESH()
-    if mesh is None or not getattr(mesh, "axis_names", None):
+    """The ambient abstract mesh, or None outside a ``set_mesh`` scope."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
 
 
-@contextlib.contextmanager
 def set_mesh(mesh: Mesh):
-    """Context manager entering ``mesh`` (jax.sharding.set_mesh compat).
-
-    On 0.4.x the internal ``set_mesh`` installs only the abstract mesh;
-    ``with_sharding_constraint`` with bare PartitionSpecs still reads the
-    legacy resource env, so enter the physical mesh context too.
-    """
-    if _SET_MESH is None:  # pragma: no cover - no ambient-mesh support
-        yield
-        return
-    if hasattr(jax.sharding, "set_mesh"):
-        with _SET_MESH(mesh):
-            yield
-        return
-    with mesh, _SET_MESH(mesh):
-        yield
+    """Context manager entering ``mesh`` as the ambient mesh."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def _axis_size(mesh: Mesh, axes) -> int:

@@ -3,15 +3,15 @@
 ``batch_part_cost`` scores a ``[N configs] x [L part-layers]`` grid through
 the analytic tiling/DRAM/compute model in one JAX call instead of ``N * L``
 scalar Python calls.  The computation mirrors ``costmodel.part_layer_cost``
-operation-for-operation in float64 (``jax.experimental.enable_x64``), so the
+operation-for-operation in float64 (:func:`repro.runtime.x64`), so the
 batched result matches the scalar reference within 1e-6 relative tolerance —
 including the chosen tiling and loop order — which the engine tests enforce.
 
 Host-side preprocessing builds, per part-layer, the same power-of-two tiling
 candidate grid the scalar model searches (padded to a common ``T`` with a
 validity mask); the per-candidate ``max(compute, dram)`` bottleneck and the
-first-argmin over candidates run in the Pallas kernel
-``kernels.dse_eval.tile_select`` (``interpret=True`` off-TPU).
+masked first-argmin over candidates are one f64 ``jnp`` reduction, the same
+expression on every backend (Mosaic compiles no f64 Pallas kernel).
 
 Batch axes:
   * configs vary ``pea_row/pea_col``, the three buffer sizes, and the
@@ -34,15 +34,14 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..core.costmodel import (MAC_ENERGY_PJ, PartCost, _sram_pj_per_bit,
                               _tile_candidates)
 from ..core.hardware import HwConfig
 from ..core.ir import Layer
 from ..core.layout import DataLayout
-from ..kernels import dse_eval
 from ..obs.trace import traced
+from ..runtime import x64
 
 INF = float("inf")
 
@@ -263,9 +262,9 @@ def _access_cost(fmap, tb, tc, th, tw, is_bhwc, group, align,
 
 
 @partial(jax.jit, static_argnames=("data_bits", "psum_bits", "dram_row_miss",
-                                   "interpret", "paired"))
+                                   "paired"))
 def _batch_cost(cfg, lay, *, data_bits: int, psum_bits: int,
-                dram_row_miss: int, interpret: bool, paired: bool = False):
+                dram_row_miss: int, paired: bool = False):
     """Score every (config, part-layer, candidate-tiling) point.
 
     ``cfg`` arrays are [N], ``lay`` per-layer arrays [L] and tile arrays
@@ -371,18 +370,13 @@ def _batch_cost(cfg, lay, *, data_bits: int, psum_bits: int,
     rows = jnp.where(use_bo, r_bo, r_ko)
     values = jnp.where(use_bo, v_bo, v_ko)
 
-    # ---- Pallas inner reduction: bottleneck + first-argmin -----------------
+    # ---- inner reduction: bottleneck + masked first-argmin ----------------
     n, l_dim = compute_cycles.shape[0], compute_cycles.shape[1]
     shape3 = (n, l_dim, t)
-    # one grid step: in interpret mode the row-block loop runs sequentially,
-    # so a full-batch block keeps the reduction a single vectorized op
-    total_flat, best_flat = dse_eval.tile_select(
-        jnp.broadcast_to(compute_cycles, shape3).reshape(n * l_dim, t),
-        jnp.broadcast_to(dram_cycles, shape3).reshape(n * l_dim, t),
-        jnp.broadcast_to(mask, shape3).reshape(n * l_dim, t),
-        block_r=n * l_dim, interpret=interpret)
-    total = total_flat.reshape(n, l_dim)
-    best = best_flat.reshape(n, l_dim)
+    cand = jnp.where(mask, jnp.maximum(compute_cycles, dram_cycles), jnp.inf)
+    total = jnp.min(cand, axis=-1)
+    # first occurrence of the min, matching np.argmin in the scalar model
+    best = jnp.argmin(cand, axis=-1).astype(jnp.int32)
 
     def pick(arr):
         full = jnp.broadcast_to(arr, shape3)
@@ -498,8 +492,8 @@ class BatchCostResult:
         {"configs": len(configs), "specs": len(specs)})
 def batch_part_cost(configs: Sequence[HwConfig],
                     specs: Sequence[PartSpec | tuple],
-                    *, chunk: int = 32, spec_chunk: int | None = None,
-                    interpret: bool | None = None) -> BatchCostResult:
+                    *, chunk: int = 32, spec_chunk: int | None = None
+                    ) -> BatchCostResult:
     """Score ``[len(configs), len(specs)]`` part-layer costs in one pipeline.
 
     ``chunk`` bounds the config-axis block handed to one jit call (the
@@ -536,8 +530,7 @@ def batch_part_cost(configs: Sequence[HwConfig],
             for tb in sorted(buckets):
                 idxs = buckets[tb]
                 sub = batch_part_cost(configs, [specs[i] for i in idxs],
-                                      chunk=chunk, spec_chunk=spec_chunk,
-                                      interpret=interpret)
+                                      chunk=chunk, spec_chunk=spec_chunk)
                 for f in fields:
                     v = getattr(sub, f)
                     if f not in merged:
@@ -553,15 +546,13 @@ def batch_part_cost(configs: Sequence[HwConfig],
             n_real = len(block)
             block = block + [block[-1]] * (spec_chunk - n_real)
             res = batch_part_cost(configs, block, chunk=chunk,
-                                  spec_chunk=spec_chunk, interpret=interpret)
+                                  spec_chunk=spec_chunk)
             blocks.append((res, n_real))
         merged = {f: np.concatenate([getattr(r, f)[:, :n] for r, n in blocks],
                                     axis=1) for f in fields}
         return BatchCostResult(configs=list(configs), specs=specs, **merged)
     lay_np = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     n = len(configs)
     chunk = max(1, min(chunk, n))
@@ -571,14 +562,13 @@ def batch_part_cost(configs: Sequence[HwConfig],
                   for k, v in cfg_np.items()}
 
     outs: dict[str, list[np.ndarray]] = {}
-    with enable_x64():
+    with x64():
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         for s in range(0, n + pad, chunk):
             cfg = {k: jnp.asarray(v[s:s + chunk]) for k, v in cfg_np.items()}
             res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
                               psum_bits=cons.psum_bits,
-                              dram_row_miss=cons.dram_row_miss_cycles,
-                              interpret=interpret)
+                              dram_row_miss=cons.dram_row_miss_cycles)
             for k, v in res.items():
                 # this per-chunk pull IS the dispatch boundary: chunks must
                 # land on host to be concatenated, and each pull overlaps
@@ -620,8 +610,7 @@ def _finalize_result(res: dict, configs, specs, cons) -> BatchCostResult:
         {"pairs": len(specs), "mode": "paired"})
 def batch_part_cost_paired(configs: Sequence[HwConfig],
                            specs: Sequence[PartSpec | tuple],
-                           *, spec_chunk: int = 1024,
-                           interpret: bool | None = None) -> BatchCostResult:
+                           *, spec_chunk: int = 1024) -> BatchCostResult:
     """Score aligned ``(config, part-layer)`` PAIRS: cell ``j`` costs
     ``specs[j]`` on ``configs[j]``.
 
@@ -662,8 +651,7 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
             idxs = buckets[tb]
             sub = batch_part_cost_paired([configs[i] for i in idxs],
                                          [specs[i] for i in idxs],
-                                         spec_chunk=spec_chunk,
-                                         interpret=interpret)
+                                         spec_chunk=spec_chunk)
             for f in _RESULT_FIELDS:
                 v = getattr(sub, f)
                 if f not in merged:
@@ -677,7 +665,7 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
         for s in range(0, len(specs), spec_chunk):
             blocks.append(batch_part_cost_paired(
                 configs[s:s + spec_chunk], specs[s:s + spec_chunk],
-                spec_chunk=spec_chunk, interpret=interpret))
+                spec_chunk=spec_chunk))
         merged = {f: np.concatenate([getattr(b, f) for b in blocks], axis=1)
                   for f in _RESULT_FIELDS}
         return BatchCostResult(configs=configs, specs=specs, **merged)
@@ -688,15 +676,13 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
         specs = specs + [specs[-1]] * (n_pad - n_real)
     lay_np = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    with enable_x64():
+    with x64():
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         cfg = {k: jnp.asarray(v) for k, v in cfg_np.items()}
         res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
                           psum_bits=cons.psum_bits,
                           dram_row_miss=cons.dram_row_miss_cycles,
-                          interpret=interpret, paired=True)
+                          paired=True)
     res = {k: np.asarray(v)[:, :n_real] for k, v in res.items()}
     return _finalize_result(res, configs[:n_real], specs[:n_real], cons)
 
@@ -714,16 +700,15 @@ def batch_area_mm2(configs: Sequence[HwConfig]) -> np.ndarray:
                  + cons.node_fixed_area_mm2)
 
 
-def batch_max_link_load(loads: np.ndarray, valid: np.ndarray | None = None,
-                        *, interpret: bool | None = None) -> np.ndarray:
+def batch_max_link_load(loads: np.ndarray, valid: np.ndarray | None = None
+                        ) -> np.ndarray:
     """Max-link-load (Eq. 4) for a batch of candidate schedules.
 
     ``loads`` is ``[S, E]`` — one row per candidate schedule, one column per
-    directed mesh link (``MeshNoc.link_loads`` order).  Runs the Pallas
-    ``max_rows`` reduction; returns ``[S]``.
+    directed mesh link (``MeshNoc.link_loads`` order); ``valid`` masks
+    links out.  A float64 NumPy row max; returns ``[S]``.
     """
-    with enable_x64():
-        out = dse_eval.max_rows(jnp.asarray(np.asarray(loads, np.float64)),
-                                None if valid is None else jnp.asarray(valid),
-                                interpret=interpret)
-        return np.asarray(out)
+    loads = np.asarray(loads, np.float64)
+    if valid is not None:
+        loads = np.where(valid, loads, -np.inf)
+    return loads.max(axis=-1)

@@ -9,8 +9,8 @@ caught the omission after the fact.
 
 ``register_jits`` builds the registry at jit-creation time::
 
-    _cycles_to_latency = jax.jit(...)
-    _JITTED = register_jits(cycles_to_latency=_cycles_to_latency)
+    _fold_keys = jax.jit(...)
+    _JITTED = register_jits(fold_keys=_fold_keys)
 
 The keyword-argument form keeps the callables visible as names in the
 ``_JITTED = ...`` assignment, which is exactly what PIM002's registry scan

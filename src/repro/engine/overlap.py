@@ -12,14 +12,12 @@ paired sweep into the two halves JAX's async dispatch already supports:
   ``batch_part_cost_paired``'s bucketing exactly (same T-buckets, same
   ``spec_chunk`` blocks, same pow2 pair padding, the same ``_batch_cost``
   programs on the same inputs), but returns a :class:`PendingPairedCost`
-  holding the ``[1, n_pad]`` device latency rows instead of blocking.
-  The cycles→seconds division runs on device (f64 under ``enable_x64``,
-  IEEE-correctly-rounded like the numpy division it replaces), so the
-  values that eventually land on host are bitwise identical to the
-  serial path's.
+  holding the ``[1, n_pad]`` device cycle rows instead of blocking.
 * :class:`PendingPairedCost` — the *resolve* half.  ``latency_row()``
-  blocks once, stitches the per-block rows back into pair order, and
-  caches the host array.
+  blocks once, stitches the per-block rows back into pair order, divides
+  cycles by the clock on the host (NumPy, like ``_finalize_result``: a
+  TPU's emulated f64 division is not correctly rounded), and caches the
+  host array — bitwise identical to the serial path's latencies.
 
 :class:`OverlapExecutor` interleaves the two across waves: ``drive``
 runs a phase generator (``PimMapper.map_many_phases``) that yields right
@@ -42,12 +40,11 @@ import threading
 from collections import deque
 from contextlib import contextmanager
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..obs import trace
+from ..runtime import x64
 from .batch_cost import (PartSpec, _batch_cost, _candidate_grid, _next_pow2,
                          _prep_configs, _prep_specs)
 from .jit_registry import register_jits
@@ -70,23 +67,19 @@ def serial_dispatch():
         _STATE.serial -= 1
 
 
-def _cycles_to_latency_fn(cycles, freq):
-    return cycles / freq
-
-
-_cycles_to_latency = jax.jit(_cycles_to_latency_fn)
-
-_JITTED = register_jits(cycles_to_latency=_cycles_to_latency)
+#: no jit of its own: the dispatches go through ``batch_cost._batch_cost``
+_JITTED = register_jits()
 
 
 class PendingPairedCost:
     """In-flight latency row of one paired sweep; resolve once, late."""
 
-    __slots__ = ("n", "_parts", "_row")
+    __slots__ = ("n", "_parts", "_freq", "_row")
 
-    def __init__(self, n: int, parts: list):
+    def __init__(self, n: int, parts: list, freq: float):
         self.n = n
         self._parts = parts
+        self._freq = freq
         self._row: np.ndarray | None = None
 
     @property
@@ -105,13 +98,13 @@ class PendingPairedCost:
         if self._row is None:
             out = np.empty(self.n, np.float64)
             for idxs, dev, n_real in self._parts:
-                out[idxs] = np.asarray(dev)[0, :n_real]
+                out[idxs] = np.asarray(dev)[0, :n_real] / self._freq
             self._row = out
             self._parts = None
         return self._row
 
 
-def _dispatch_block(configs, specs, idxs, t_pad, spec_chunk, interpret):
+def _dispatch_block(configs, specs, idxs, t_pad, spec_chunk):
     """One ``_batch_cost`` leaf — same padding/programs as the serial path."""
     n_real = len(specs)
     n_pad = min(spec_chunk, _next_pow2(max(128, n_real)))
@@ -120,22 +113,17 @@ def _dispatch_block(configs, specs, idxs, t_pad, spec_chunk, interpret):
         specs = specs + [specs[-1]] * (n_pad - n_real)
     lay_np = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    with enable_x64():
+    with x64():
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         cfg = {k: jnp.asarray(v) for k, v in cfg_np.items()}
         res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
                           psum_bits=cons.psum_bits,
                           dram_row_miss=cons.dram_row_miss_cycles,
-                          interpret=interpret, paired=True)
-        lat = _cycles_to_latency(res["total_cycles"],
-                                 jnp.asarray(cons.freq_hz, dtype=jnp.float64))
-    return idxs, lat, n_real
+                          paired=True)
+    return idxs, res["total_cycles"], n_real
 
 
-def dispatch_paired_latency(configs, specs, *, spec_chunk: int = 1024,
-                            interpret: bool | None = None
+def dispatch_paired_latency(configs, specs, *, spec_chunk: int = 1024
                             ) -> PendingPairedCost:
     """Async twin of ``batch_part_cost_paired(...).latency_s[0]``.
 
@@ -164,8 +152,8 @@ def dispatch_paired_latency(configs, specs, *, spec_chunk: int = 1024,
                 blk = idxs[s:s + spec_chunk]
                 parts.append(_dispatch_block(
                     [configs[i] for i in blk], [specs[i] for i in blk],
-                    np.asarray(blk, np.intp), tb, spec_chunk, interpret))
-    pending = PendingPairedCost(len(specs), parts)
+                    np.asarray(blk, np.intp), tb, spec_chunk))
+    pending = PendingPairedCost(len(specs), parts, configs[0].cons.freq_hz)
     if not overlap_enabled():
         pending.latency_row()
     return pending

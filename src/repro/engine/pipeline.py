@@ -42,6 +42,7 @@ import numpy as np
 from ..core.hardware import (HwConfig, normalize_params_batch,
                              sample_config_values)
 from ..obs import trace
+from ..runtime import native_kernels
 from .jit_registry import register_jits
 from .tuner_train import mlp_forward, score_candidates
 
@@ -167,12 +168,11 @@ class DsePipeline:
         if getattr(tuner, "backend", None) != "scan":
             raise ValueError("DsePipeline requires a scan-backend tuner "
                              f"(got backend={getattr(tuner, 'backend', None)!r})")
-        # lazy: core.tuner imports this package's tuner_train at its top
-        # level, so a module-level import here would be circular
-        from ..core.tuner import _USE_PALLAS
         self.tuner = tuner
         self.name = getattr(tuner, "name", "nicepim")
-        self._use_pallas = _USE_PALLAS
+        # the fused Pallas LCB kernel where it compiles natively; the jnp
+        # scoring elsewhere (interpret-mode Pallas is only a test path)
+        self._use_pallas = native_kernels()
         # scalars/constants the jitted stages consume, pre-staged once so
         # steady-state proposals perform no implicit host->device transfer
         self._beta = jax.device_put(np.float32(tuner.suggestion.beta))

@@ -16,8 +16,9 @@ Array form of the Sec. VII joint min-max-link-load Hamilton-cycle search
 * each round draws ``moves_per_round`` jax-PRNG proposals per row (uniform
   over the valid ``i < j`` reversal pairs, the degenerate full-cycle reversal
   excluded by rank arithmetic rather than rejection), scores every proposal's
-  max-link-load against the current loads (Pallas ``delta_maxload_rows`` on
-  TPU, plain ``jnp`` otherwise), applies the best non-worsening move of
+  max-link-load against the current loads (Pallas ``delta_maxload_rows``
+  where kernels compile natively, plain ``jnp`` otherwise — see
+  :func:`repro.runtime.native_kernels`), applies the best non-worsening move of
   every sharing-set jointly, and exactly re-checks the combined objective —
   falling back to the single globally best move when overlapping routes make
   the combination worse, so the objective is monotone non-increasing like
@@ -45,16 +46,14 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..core.noc import MeshNoc
 from ..core.scheduler import (ScheduleResult, _all_transfers, _finish,
                               _initial_cycles, _solve_exact)
 from ..obs import metrics, trace
+from ..runtime import native_kernels, x64
 from .jit_registry import register_jits
 from .tuner_train import pow2_bucket
-
-_USE_PALLAS = jax.default_backend() == "tpu"
 
 # pad mesh-dependent shapes (node count, link count) to pow2 so every mesh
 # with the same padded envelope reuses ONE compiled program — per-mesh
@@ -101,6 +100,31 @@ def _mesh_pads(noc: MeshNoc, pad: bool) -> tuple[int, int]:
 
 
 # -- the jitted multi-chain search --------------------------------------------
+
+
+def _randint(key, span, m: int, n_max: int):
+    """``jax.random.randint(key, (m,), 0, span)`` under x64, bit for bit.
+
+    x64's ``randint`` draws two uint64 words per value and reduces
+    ``(hi * 2**64 + lo) mod span`` in uint64 arithmetic, which the TPU
+    emulates at a cost of ~40 s of compile time per scan program.  While
+    every span stays below ``2**16`` (set sizes ``n_max <= 362``) the same
+    residue is exact in uint32, one 32-bit half-word at a time; larger
+    sets take ``jax.random.randint`` itself.
+    """
+    if n_max * (n_max - 1) // 2 > 1 << 16:
+        return jax.random.randint(key, (m,), 0, span)
+    s = span.astype(jnp.uint32)
+    p32 = (jnp.uint32(1 << 16) % s) ** 2 % s               # 2**32 mod s
+    p64 = p32 * p32 % s                                     # 2**64 mod s
+
+    def residue(k):   # one uint64 word mod s
+        b = jax.random.bits(k, (m,), jnp.uint64)
+        hi, lo = (b >> 32).astype(jnp.uint32), b.astype(jnp.uint32)
+        return (hi % s * p32 + lo % s) % s
+
+    k_hi, k_lo = jax.random.split(key)
+    return ((residue(k_hi) * p64 + residue(k_lo)) % s).astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -164,7 +188,7 @@ def _scan_solve(cycles0, lens, weights, loads0, keys, inc, *,
         # reversal (0, n-1) has rank n-2 and is skipped by shifting — every
         # draw lands on a real 2-opt move, honoring the move budget
         cnt = n * (n - 1) // 2 - 1
-        r = jax.vmap(lambda k, c: jax.random.randint(k, (M,), 0, c))(
+        r = jax.vmap(lambda k, c: _randint(k, c, M, N))(
             k_r, jnp.maximum(cnt, 1))
         r = r + (r >= n - 2)
         t = jnp.minimum(jnp.arange(1, N)[None, None, :], (n - 1)[..., None])
@@ -501,7 +525,7 @@ def _pack_solve(setups: list[_Setup], *, rounds: int, moves_per_round: int,
         cycles0[row], lens[row] = cycles0[0], lens[0]
         weights[row], loads0[row], keys[row] = (weights[0], loads0[0],
                                                 keys[0])
-    with enable_x64():
+    with x64():
         inc = (_mesh_incidence(noc, *_mesh_pads(noc, True)) if pad_shapes
                else _mesh_incidence(noc))
         # cycles0/loads0 are donated by _scan_solve — freshly packed per
@@ -547,7 +571,7 @@ def schedule_many(problems, link_bw: float, freq: float,
     meshes and nearby problem shapes share compiled programs; results are
     bit-identical with or without padding — only compile count changes.
     """
-    use_pallas = _USE_PALLAS if use_pallas is None else use_pallas
+    use_pallas = native_kernels() if use_pallas is None else use_pallas
     pad_shapes = _PAD_SHAPES if pad_shapes is None else pad_shapes
     rounds = _rounds(iters, moves_per_round)
     with trace.span("schedule_many", cat="engine",
@@ -602,6 +626,6 @@ def _solve_one_scan(noc: MeshNoc, sharing_sets, chunk_bytes, link_bw: float,
     _, s_pad, n_pad = _bucket_key(st, _PAD_SHAPES)
     per_chain = _run_bucket([st], rounds=_rounds(iters, moves_per_round),
                             moves_per_round=moves_per_round, s_pad=s_pad,
-                            n_pad=n_pad, use_pallas=_USE_PALLAS,
+                            n_pad=n_pad, use_pallas=native_kernels(),
                             pad_shapes=_PAD_SHAPES)[0]
     return _finish_chains(st, per_chain, link_bw, freq, pj_per_bit_hop)

@@ -11,10 +11,12 @@ of them against shared infrastructure:
   (:func:`campaign_mesh`, built on :func:`repro.distributed.shardings.
   make_mesh`; ``--xla_force_host_platform_device_count`` makes it
   CPU-testable).  The jitted stages — area mask, fused candidate scoring,
-  in-array top-k — are row-local, so GSPMD partitions them across the mesh
-  and the proposals stay BITWISE identical to the single-device pipeline
-  (pinned by ``tests/test_sharded.py``); per-wave legality stats reduce on
-  device through an explicit ``shard_map`` kernel.
+  in-array top-k — are row-local, so they split across the mesh (GSPMD
+  for the jnp stages, an explicit ``shard_map`` for the scoring, whose
+  Pallas kernel XLA cannot partition) and the proposals stay BITWISE
+  identical to the single-device pipeline (pinned by
+  ``tests/test_sharded.py``); per-wave legality stats reduce on device
+  through a ``shard_map`` kernel too.
 
 * **async wave overlap** — the run loop is a bounded producer/consumer:
   the main thread proposes/ingests/fits (per-tenant sequential semantics,
@@ -60,7 +62,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.dse import (DseResult, Observation, WorkloadEvaluator,
@@ -79,14 +80,25 @@ from .pareto import ParetoFront
 from .jit_registry import register_jit
 from .pipeline import (DsePipeline, ProposalHandle, _area_mask,
                        _masked_zeros, _select_topk)
-from .tuner_train import score_candidates
+from .tuner_train import _score_candidates_jit, score_candidates
 
 #: module jit registry (PIM002 / ``engine_program_counts`` contract).  The
-#: shard_map wave-stats kernel closes over a concrete mesh, so it is built
-#: lazily per mesh and registered here under ``wave_stats[<ndev>]``.
+#: shard_map programs close over a concrete mesh, so they are built lazily
+#: per mesh and registered here as ``<name>[<ndev>]``.
 _JITTED: dict = {}
 
-_WAVE_STATS_MESHES: dict = {}
+_PER_MESH: dict = {}
+
+
+def _per_mesh(name: str, mesh, build):
+    """The ``name`` program of ``mesh``, built once by ``build()``."""
+    fn = _PER_MESH.get((name, mesh))
+    if fn is None:
+        if len(_PER_MESH) >= 16:   # bounded: meshes are few
+            _PER_MESH.clear()
+        fn = _PER_MESH[(name, mesh)] = build()
+        register_jit(_JITTED, f"{name}[{mesh.devices.size}]", fn)
+    return fn
 
 
 # --------------------------------------------------------------------------
@@ -126,21 +138,33 @@ def _wave_stats_for(mesh):
     across the ``config`` axis — both order-independent, so the stats are
     deterministic under any device count.
     """
-    fn = _WAVE_STATS_MESHES.get(mesh)
-    if fn is None:
-        def _stats(scores, ok):
-            legal = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), "config")
-            best = jax.lax.pmin(
-                jnp.min(jnp.where(ok, scores, jnp.inf)), "config")
-            return legal, best
-        fn = jax.jit(shard_map(_stats, mesh=mesh,
-                               in_specs=(P("config"), P("config")),
-                               out_specs=(P(), P())))
-        if len(_WAVE_STATS_MESHES) >= 8:   # bounded: meshes are few
-            _WAVE_STATS_MESHES.clear()
-        _WAVE_STATS_MESHES[mesh] = fn
-        register_jit(_JITTED, f"wave_stats[{mesh.devices.size}]", fn)
-    return fn
+    def _stats(scores, ok):
+        legal = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), "config")
+        best = jax.lax.pmin(
+            jnp.min(jnp.where(ok, scores, jnp.inf)), "config")
+        return legal, best
+    return _per_mesh("wave_stats", mesh, lambda: jax.jit(jax.shard_map(
+        _stats, mesh=mesh, in_specs=(P("config"), P("config")),
+        out_specs=(P(), P()))))
+
+
+def _scores_for(mesh, use_pallas: bool):
+    """Per-mesh ``shard_map`` of the fused candidate scoring.
+
+    Each device scores its own row block against the replicated model and
+    training set.  XLA cannot partition a Pallas kernel by itself, so the
+    native ``lcb_rows`` path needs the explicit per-device split; the jnp
+    path takes the same one, and scoring stays row-local either way.
+    ``check_vma`` is off because a Pallas call declares no per-axis
+    variance for its output.
+    """
+    def _score(params, xt, yt, mask, xq, ok, beta):
+        return _score_candidates_jit(params, xt, yt, mask, xq, ok, beta,
+                                     use_pallas=use_pallas)
+    name = "scores_pallas" if use_pallas else "scores"
+    return _per_mesh(name, mesh, lambda: jax.jit(jax.shard_map(
+        _score, mesh=mesh, in_specs=(P(),) * 4 + (P("config"),) * 2 + (P(),),
+        out_specs=P("config"), check_vma=False)))
 
 
 # --------------------------------------------------------------------------
@@ -153,7 +177,7 @@ class ShardedProposer(DsePipeline):
     Same RNG stream, same jitted stage programs, same selection walk — the
     ONLY change is placement: candidate row arrays enter the chain sharded
     ``P("config")`` and the model/train-set arrays enter replicated, so
-    GSPMD partitions the row-local stage math across the mesh.  Proposals
+    the row-local stage math splits across the mesh.  Proposals
     are bitwise identical to the base pipeline (row-local elementwise ops
     and matmul rows don't change under partitioning; the top-k sort sees
     identical scores), which is what lets a sharded campaign share one
@@ -208,9 +232,15 @@ class ShardedProposer(DsePipeline):
         if sg._dirty or sg._train is None:
             sg.fit_arrays()
         xp, yp, mask = self._replicate(sg._train)
-        return score_candidates(self._replicate(sg.params), xp, yp, mask,
-                                xq, ok, self._beta,
-                                use_pallas=self._use_pallas)
+        params = self._replicate(sg.params)
+        if not self._sharded:
+            return score_candidates(params, xp, yp, mask, xq, ok,
+                                    self._beta, use_pallas=self._use_pallas)
+        with trace.span("score_candidates", cat="engine",
+                        bucket=int(yp.shape[0]), candidates=int(xq.shape[0]),
+                        devices=self.mesh.devices.size):
+            return _scores_for(self.mesh, self._use_pallas)(
+                params, xp, yp, mask, xq, ok, self._beta)
 
 
 # --------------------------------------------------------------------------

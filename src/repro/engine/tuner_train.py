@@ -16,9 +16,10 @@ moves the whole tuner/surrogate stack onto the engine layer:
   full candidate batch in a single dispatch: MLP features, RBF cross-kernel,
   GP posterior mean/variance, and the LCB, with the filter-model area mask
   applied in-array (masked-out candidates score ``+inf``).  The
-  pairwise-distance + LCB reduction can run in the Pallas kernel
-  :func:`repro.kernels.dse_eval.lcb_rows` (``use_pallas=True``, the on-TPU
-  default in the models; interpret-mode fallback off-TPU).
+  pairwise-distance + LCB reduction can run in the f32 Pallas kernel
+  :func:`repro.kernels.dse_eval.lcb_rows` (``use_pallas=True``; the models
+  pass :func:`repro.runtime.native_kernels`, so a TPU runs the kernel and
+  other backends the jnp path).
 
 Masking contract (the jitter-on-the-padded-diagonal trick): padded
 rows/columns of the training kernel are zeroed and their diagonal pinned to

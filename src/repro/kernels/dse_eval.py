@@ -1,34 +1,36 @@
-"""Pallas reductions for the batched DSE engine (engine/batch_cost).
+"""Pallas row reductions of the DSE engine.
 
-Two row-wise reductions sit on the engine's hot path:
+Two kernels are on the main path, both in float32:
 
-* ``tile_select`` — the inner tiling-search reduction: for every
-  (config, part-layer) row holding ``T`` candidate tilings, fuse the
-  double-buffering bottleneck ``total = max(compute_cycles, dram_cycles)``
-  with a masked first-argmin over candidates.
-* ``max_rows`` — the max-link-load reduction: row-wise masked max, used to
-  score batches of candidate NoC schedules (one row per schedule, one column
-  per directed mesh link).
 * ``delta_maxload_rows`` — the engine Data-Scheduler's move scoring: fuse
   the ``base + delta`` link-load accumulation of a whole 2-opt proposal
   batch with the per-proposal max-link reduction (one row per search chain,
   one slab per proposed segment reversal).
-* ``minplus_rows`` — the Algorithm-2 *segment* min-plus convolution: fuse the
-  ``a[i] + b[r, i]`` broadcast-add with the row-wise min + first-argmin that
-  combines per-segment DP tables under one shared capacity budget.
 * ``lcb_rows`` — the PIM-Tuner's fused propose reduction: for every query
   feature row, the pairwise squared distance to the (masked) training
   features, the RBF cross-kernel, the GP posterior mean/variance against a
   precomputed ``K^-1`` / ``K^-1 y``, and the lower-confidence-bound score,
   all in one pass.
 
-The kernels tile rows across the grid; most keep the full reduction axis in
-one VMEM block, while ``delta_maxload_rows`` *streams* the link axis (the
-innermost grid dimension walks link tiles with a running max in the
-revisited output block, double-buffered by the Pallas pipeline).  Off-TPU
-they run in ``interpret=True`` mode (this container's validation path),
-matching the pure-jnp semantics bit-for-bit — which the engine relies on
-for its 1e-6 parity contract with the scalar cost model.
+Four more have no main-path caller; their tests keep them honest:
+
+* ``tile_select`` — fused ``max(compute, dram)`` + masked first-argmin over
+  candidate tilings;
+* ``argmin_rows`` / ``minplus_rows`` — the Algorithm-2 knapsack and segment
+  min-plus reductions (``PimMapper(dp_reduce="pallas")``);
+* ``max_rows`` — row-wise masked max.
+
+Their main-path call sites run in float64 (the engine's 1e-6 parity
+contract with the scalar cost model), which Mosaic does not compile, so
+those sites use the same reduction as a plain jnp/NumPy expression on
+every backend.
+
+Blocks follow the TPU tiling: a block's last two dims are (8, 128)
+aligned or whole, so per-row results come out as ``[rows, 1]`` columns.
+``delta_maxload_rows`` *streams* the link axis (the innermost grid
+dimension walks link tiles with a running max in the revisited output
+block).  Kernels compile natively on a TPU backend and run in interpret
+mode elsewhere (:func:`repro.runtime.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -38,10 +40,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..runtime import resolve_interpret
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _I0():
+    """Block index 0 as int32: a bare ``0`` in an index map traces as int64
+    under x64 (the scheduler scores moves inside its f64 scope), which
+    Mosaic refuses."""
+    return jnp.int32(0)
 
 
 def _tile_select_kernel(c_ref, d_ref, v_ref, tot_ref, idx_ref):
@@ -77,7 +85,7 @@ def tile_select(compute_cycles, dram_cycles, valid, *, block_r: int = 8,
     Rows with no valid candidate return ``inf`` / index 0 — the caller
     (engine/batch_cost) guarantees at least the fallback tiling is valid.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     r, t = compute_cycles.shape
     block_r = max(1, min(block_r, r))
     pad = (-r) % block_r
@@ -122,7 +130,7 @@ def argmin_rows(x, valid=None, *, block_r: int = 128,
     column per layer candidate.  Rows with no valid (finite) candidate return
     ``inf`` / index 0; the caller maps those back to "no choice".
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     x = jnp.asarray(x)
     if valid is None:
         valid = jnp.ones(x.shape, dtype=bool)
@@ -172,7 +180,7 @@ def minplus_rows(a, b, *, block_r: int = 128, interpret: bool | None = None):
     ``inf`` (no feasible split) return index 0; the caller maps those back to
     "no choice", exactly like :func:`argmin_rows`.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     a = jnp.asarray(a)
     b = jnp.asarray(b)
     r, t = b.shape
@@ -193,10 +201,11 @@ def _lcb_rows_kernel(zq_ref, zt_ref, alpha_ref, kinv_ref, v_ref, par_ref,
     kq = sf2 * jnp.exp(-0.5 * d2 / ls2)
     # padded training rows contribute nothing: their cross-kernel column is
     # zeroed, and the padded block of kinv is the identity by construction
-    kq = jnp.where(v_ref[...][None, :], kq, 0.0)
-    mean = kq @ alpha_ref[...]
+    kq = jnp.where(v_ref[...], kq, 0.0)                       # v: [1, N]
+    mean = jnp.dot(kq, alpha_ref[...],                        # [bq, 1]
+                   preferred_element_type=kq.dtype)
     t = jnp.dot(kq, kinv_ref[...], preferred_element_type=kq.dtype)
-    var = sf2 - jnp.sum(t * kq, axis=-1)
+    var = sf2 - jnp.sum(t * kq, axis=-1, keepdims=True)
     out_ref[...] = mean - beta * jnp.sqrt(jnp.clip(var, 1e-9))
 
 
@@ -206,19 +215,23 @@ def _lcb_rows(zq, zt, alpha, kinv, valid, params, *, block_q: int,
     q, d = zq.shape
     n = zt.shape[0]
     grid = (pl.cdiv(q, block_q),)
+    # TPU tiling: every block is 2-D with its last two dims either (8, 128)
+    # aligned or whole; the per-query result is a [bq, 1] column (a 1-D
+    # block would have to match XLA's own tiling of the [Q] array), and the
+    # three scalars ride in SMEM
     return pl.pallas_call(
         _lcb_rows_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_q, d), lambda i: (i, 0)),
-                  pl.BlockSpec((n, d), lambda i: (0, 0)),
-                  pl.BlockSpec((n,), lambda i: (0,)),
-                  pl.BlockSpec((n, n), lambda i: (0, 0)),
-                  pl.BlockSpec((n,), lambda i: (0,)),
-                  pl.BlockSpec((3,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((block_q,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q,), zq.dtype),
+        in_specs=[pl.BlockSpec((block_q, d), lambda i: (i, _I0())),
+                  pl.BlockSpec((n, d), lambda i: (_I0(), _I0())),
+                  pl.BlockSpec((n, 1), lambda i: (_I0(), _I0())),
+                  pl.BlockSpec((n, n), lambda i: (_I0(), _I0())),
+                  pl.BlockSpec((1, n), lambda i: (_I0(), _I0())),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((block_q, 1), lambda i: (i, _I0())),
+        out_shape=jax.ShapeDtypeStruct((q, 1), zq.dtype),
         interpret=interpret,
-    )(zq, zt, alpha, kinv, valid, params)
+    )(zq, zt, alpha.reshape(n, 1), kinv, valid.reshape(1, n), params)
 
 
 def lcb_rows(zq, zt, alpha, kinv, valid, ls2, sf2, beta, *,
@@ -232,7 +245,7 @@ def lcb_rows(zq, zt, alpha, kinv, valid, ls2, sf2, beta, *,
     ``kinv`` are the precomputed ``K^-1 y`` / ``K^-1`` of the (masked)
     training kernel; invalid (padded) training rows are dropped via ``valid``.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     zq = jnp.asarray(zq)
     zt = jnp.asarray(zt)
     params = jnp.stack([jnp.asarray(ls2, zq.dtype), jnp.asarray(sf2, zq.dtype),
@@ -245,7 +258,7 @@ def lcb_rows(zq, zt, alpha, kinv, valid, ls2, sf2, beta, *,
     out = _lcb_rows(zq, zt, jnp.asarray(alpha), jnp.asarray(kinv),
                     jnp.asarray(valid), params, block_q=block_q,
                     interpret=interpret)
-    return out[:q]
+    return out[:q, 0]
 
 
 def _delta_maxload_rows_kernel(b_ref, d_ref, w_ref, o_ref):
@@ -256,8 +269,8 @@ def _delta_maxload_rows_kernel(b_ref, d_ref, w_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref[...], -jnp.inf)
-    d = d_ref[...].astype(o_ref.dtype) * w_ref[...][..., None]
-    part = jnp.max(b_ref[...][:, None, :] + d, axis=-1)
+    d = d_ref[...].astype(o_ref.dtype) * w_ref[...]           # [bm, be]
+    part = jnp.max(b_ref[...] + d, axis=-1, keepdims=True)    # [bm, 1]
     o_ref[...] = jnp.maximum(o_ref[...], part)
 
 
@@ -267,17 +280,24 @@ def _delta_maxload_rows(base, deltas, weights, *, block_m: int,
                         block_e: int, interpret: bool):
     r, m, e = deltas.shape
     grid = (r, pl.cdiv(m, block_m), pl.cdiv(e, block_e))
-    return pl.pallas_call(
+    # TPU tiling: the row axis is squeezed (None) and every block's last
+    # two dims are (8, 128) aligned or whole — base rides as [R, 1, E],
+    # the per-proposal weights and results as [R, M, 1] columns
+    out = pl.pallas_call(
         _delta_maxload_rows_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, block_e), lambda i, j, k: (i, k)),
-                  pl.BlockSpec((1, block_m, block_e),
+        in_specs=[pl.BlockSpec((None, 1, block_e),
+                               lambda i, j, k: (i, _I0(), k)),
+                  pl.BlockSpec((None, block_m, block_e),
                                lambda i, j, k: (i, j, k)),
-                  pl.BlockSpec((1, block_m), lambda i, j, k: (i, j))],
-        out_specs=pl.BlockSpec((1, block_m), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, m), base.dtype),
+                  pl.BlockSpec((None, block_m, 1),
+                               lambda i, j, k: (i, j, _I0()))],
+        out_specs=pl.BlockSpec((None, block_m, 1),
+                               lambda i, j, k: (i, j, _I0())),
+        out_shape=jax.ShapeDtypeStruct((r, m, 1), base.dtype),
         interpret=interpret,
-    )(base, deltas, weights)
+    )(base.reshape(r, 1, e), deltas, weights.reshape(r, m, 1))
+    return out[:, :, 0]
 
 
 def delta_maxload_rows(base, deltas, weights=None, *, block_m: int = 128,
@@ -301,7 +321,7 @@ def delta_maxload_rows(base, deltas, weights=None, *, block_m: int = 128,
     tiles with a running max in the revisited output block, so the 960-link
     16x16 mesh no longer needs the whole E axis resident per block.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     base = jnp.asarray(base)
     deltas = jnp.asarray(deltas)
     r, m, e = deltas.shape
@@ -348,7 +368,7 @@ def _max_rows(x, valid, *, block_r: int, interpret: bool):
 def max_rows(x, valid=None, *, block_r: int = 8,
              interpret: bool | None = None):
     """Row-wise masked max — the Eq. 4 max-link-load reduction, batched."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     x = jnp.asarray(x)
     if valid is None:
         valid = jnp.ones(x.shape, dtype=bool)

@@ -237,14 +237,14 @@ def test_minplus_monotone_fill_matches_sequential():
 
 
 def test_minplus_rows_kernel_matches_numpy():
-    from jax.experimental import enable_x64
     from repro.kernels import dse_eval
+    from repro.runtime import x64
     rng = np.random.default_rng(9)
     a = rng.uniform(0.0, 4.0, 33)
     a[rng.random(33) < 0.25] = INF
     b = rng.uniform(0.0, 4.0, (17, 33))
     b[rng.random((17, 33)) < 0.25] = INF
-    with enable_x64():  # the DP runs the kernel in f64, like the engine
+    with x64():  # the DP runs the kernel in f64, like the engine
         mn, idx = dse_eval.minplus_rows(a, b, block_r=4)
     scores = a[None, :] + b
     np.testing.assert_array_equal(np.asarray(mn), scores.min(axis=1))

@@ -304,6 +304,28 @@ def test_no_eligible_sets_matches_loop():
     assert scan.cycles == loop.cycles
 
 
+@pytest.mark.parametrize("n_max", [256, 400], ids=["uint32", "fallback"])
+def test_scan_randint_matches_jax_randint(n_max):
+    """The scan's move draws equal x64 ``jax.random.randint`` bit for bit,
+    over every span a set of ``n_max`` nodes can give."""
+    import jax
+    import jax.numpy as jnp
+    from repro.engine.scheduler_opt import _randint
+    from repro.runtime import x64
+    rng = np.random.default_rng(3)
+    top = n_max * (n_max - 1) // 2 - 1
+    spans = np.concatenate([np.arange(1, 600), [top - 1, top],
+                            rng.integers(1, top + 1, 4000)]).astype(np.int32)
+    with x64():
+        keys = jax.vmap(jax.random.PRNGKey)(
+            jnp.asarray(rng.integers(0, 2**31, spans.size)))
+        got = jax.jit(jax.vmap(lambda k, c: _randint(k, c, 16, n_max)))(
+            keys, jnp.asarray(spans))
+        ref = jax.jit(jax.vmap(lambda k, c: jax.random.randint(
+            k, (16,), 0, c)))(keys, jnp.asarray(spans))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
 # ---------------------------------------------------------------------------
 # Pallas delta_maxload_rows kernel
 # ---------------------------------------------------------------------------
