@@ -303,8 +303,7 @@ def _campaign_metrics(par: dict) -> list[str]:
     keep = [k for k in sorted(metrics)
             if k.startswith(("eval_cache.", "pareto.", "campaign."))
             or k.endswith((".best_cost", ".legal_fraction"))
-            or k.startswith("tuner.bucket_fill")
-            or k.startswith("scheduler.bucket_fill")]
+            or k.startswith("tuner.bucket_fill")]
     lines = ["Campaign telemetry (metrics registry snapshot):", "",
              "| metric | value |", "|---|---|"]
     for k in keep:

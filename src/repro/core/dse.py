@@ -190,9 +190,10 @@ class WorkloadEvaluator:
         try:
             for g in self.workloads:
                 try:
-                    rep = evaluate_mapping(
-                        mapper.map(g),
-                        scheduler_backend=self.scheduler_backend)
+                    m = mapper.map(g)
+                    with trace.span("accounting"):
+                        rep = evaluate_mapping(
+                            m, scheduler_backend=self.scheduler_backend)
                 except RuntimeError:   # capacity-infeasible mapping
                     # earlier workloads' numbers must not leak into the
                     # caches alongside the inf cost: an infeasible config
@@ -343,8 +344,9 @@ class WorkloadEvaluator:
                 costs[k] = math.inf     # as __call__ — nothing leaks
                 lats[k], ens[k] = {}, {}
                 continue
-            rep = evaluate_mapping(
-                m, scheduler_backend=self.scheduler_backend)
+            with trace.span("accounting"):
+                rep = evaluate_mapping(
+                    m, scheduler_backend=self.scheduler_backend)
             lats[k][g.name] = rep.latency_s
             ens[k][g.name] = rep.energy_pj
             energy_j = rep.energy_pj * 1e-12

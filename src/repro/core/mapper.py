@@ -24,7 +24,7 @@ import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from functools import lru_cache
-from typing import Sequence
+from typing import Iterable, Iterator, Sequence
 
 from .costmodel import part_layer_cost
 from .hardware import HwConfig
@@ -371,36 +371,45 @@ class _PendingTables:
 
     def resolve(self) -> dict[tuple, tuple]:
         if self._work:
-            fresh = self._fill.resolve()
-            for hw, key, struct, specs in self._work:
-                node_lat = _node_lat_from(fresh, hw, specs)
-                table = _layer_candidates_batched(struct, node_lat)
-                self._out[key] = table
-                _BATCH_CANDS.put(key, table)
+            with trace.span("cand_build", cat="mapper",
+                            tables=len(self._work)):
+                fresh = self._fill.resolve()
+                for hw, key, struct, specs in self._work:
+                    node_lat = _node_lat_from(fresh, hw, specs)
+                    table = _layer_candidates_batched(struct, node_lat)
+                    self._out[key] = table
+                    _BATCH_CANDS.put(key, table)
             self._work = ()
         return self._out
 
 
 def _dispatch_candidates_multi(key_lists) -> _PendingTables:
-    """Dispatch phase of :func:`_prefetch_candidates_multi`."""
-    out: dict[tuple, tuple] = {}
-    work = []
-    for keys in key_lists:
-        for key in keys:
-            if key in out:
-                continue
-            got = _BATCH_CANDS.get(key)
-            if got is None:
-                out[key] = ()  # placeholder: dedupes repeated missing keys
-                hw, layer, h, w, din, dout, n_wr, lm_cap = key
-                struct = _cand_struct(hw, layer, h, w, n_wr, lm_cap)
-                work.append((hw, key, struct,
-                             [(pl, din, dout) for pl in struct.uniq_pls]))
-            else:
-                out[key] = got
-    if not work:
-        return _PendingTables(out, (), None)
-    fill = _dispatch_node_fill([(hw, specs) for hw, _, _, specs in work])
+    """Dispatch phase of :func:`_prefetch_candidates_multi`.
+
+    The key lists may be lazy: the ``cand_dispatch`` span covers the key
+    enumeration that iterating them runs, and counts the distinct ``keys``
+    and the tables ``built`` (not in ``_BATCH_CANDS``).
+    """
+    with trace.span("cand_dispatch", cat="mapper") as sp:
+        out: dict[tuple, tuple] = {}
+        work = []
+        for keys in key_lists:
+            for key in keys:
+                if key in out:
+                    continue
+                got = _BATCH_CANDS.get(key)
+                if got is None:
+                    out[key] = ()  # placeholder: dedupes repeated misses
+                    hw, layer, h, w, din, dout, n_wr, lm_cap = key
+                    struct = _cand_struct(hw, layer, h, w, n_wr, lm_cap)
+                    work.append((hw, key, struct,
+                                 [(pl, din, dout) for pl in struct.uniq_pls]))
+                else:
+                    out[key] = got
+        sp["keys"], sp["built"] = len(out), len(work)
+        if not work:
+            return _PendingTables(out, (), None)
+        fill = _dispatch_node_fill([(hw, specs) for hw, _, _, specs in work])
     return _PendingTables(out, work, fill)
 
 
@@ -728,7 +737,8 @@ class PimMapper:
             got = self._prefetch_candidates([key])[key]
         return got
 
-    def _prefetch_candidates(self, keys: list[tuple]) -> dict[tuple, tuple]:
+    def _prefetch_candidates(self, keys: Iterable[tuple]
+                             ) -> dict[tuple, tuple]:
         """Cost every missing candidate table in one batched engine call.
 
         Returns a table per requested key.  Callers consume the returned
@@ -764,7 +774,12 @@ class PimMapper:
         mapping: Mapping | None = None
         for it in range(self.max_optim_iter):
             mapping = self._solve_sm_lm_wr(graph, segments, dls)
-            dls = self._optimize_dl(graph, mapping, dls)
+            with trace.span("dl_optimize", cat="mapper") as sp:
+                table = None
+                if self.backend == "batched":
+                    table = self._dl_sweep_table(graph, mapping)
+                    sp["specs"] = len(table)
+                dls = self._optimize_dl(graph, mapping, dls, table=table)
             for name, ch in mapping.choices.items():
                 ch.dl_in, ch.dl_out = dls[name]
         return mapping
@@ -844,10 +859,12 @@ class PimMapper:
         alive = list(range(len(subs)))
         seg_sms = {i: subs[i]._seg_sms(graph, segments)
                    for i in range(len(subs))}
+        # every span below closes before the next yield: the executor runs
+        # deferred work there, and it must not nest inside these phases
         for _ in range(self.max_optim_iter):
             pending_tables = _dispatch_candidates_multi(
-                [subs[i]._solve_keys(graph, segments, seg_sms[i], dls[i])
-                 for i in alive])
+                subs[i]._solve_keys(graph, segments, seg_sms[i], dls[i])
+                for i in alive)
             yield pending_tables  # candidate costs in flight
             # the resolved tables are handed straight to each sub's solve —
             # a batch whose key union exceeds the _BATCH_CANDS bound must
@@ -863,20 +880,25 @@ class PimMapper:
                         raise
                     mappings[i] = None
                     alive.remove(i)
-            sweeps = {i: subs[i]._dl_sweep_specs(graph, mappings[i])
-                      for i in alive}
-            pending_fill = _dispatch_node_fill(
-                [(subs[i].hw, sweeps[i][1]) for i in alive])
+            with trace.span("dl_dispatch", cat="mapper") as sp:
+                sweeps = {i: subs[i]._dl_sweep_specs(graph, mappings[i])
+                          for i in alive}
+                pending_fill = _dispatch_node_fill(
+                    [(subs[i].hw, sweeps[i][1]) for i in alive])
+                if trace.current() is not None:
+                    sp["specs"] = sum(len(sweeps[i][1]) for i in alive)
             yield pending_fill  # DL-sweep costs in flight
-            fresh = pending_fill.resolve()
-            for i in alive:
-                entries, specs = sweeps[i]
-                lat = _node_lat_from(fresh, subs[i].hw, specs)
-                table = {e: float(l) for e, l in zip(entries, lat)}
-                dls[i] = subs[i]._optimize_dl(graph, mappings[i], dls[i],
-                                              table=table)
-                for name, ch in mappings[i].choices.items():
-                    ch.dl_in, ch.dl_out = dls[i][name]
+            # the same counts as its dispatch (none when tracing is off)
+            with trace.span("dl_optimize", cat="mapper", **sp):
+                fresh = pending_fill.resolve()
+                for i in alive:
+                    entries, specs = sweeps[i]
+                    lat = _node_lat_from(fresh, subs[i].hw, specs)
+                    table = {e: float(l) for e, l in zip(entries, lat)}
+                    dls[i] = subs[i]._optimize_dl(graph, mappings[i], dls[i],
+                                                  table=table)
+                    for name, ch in mappings[i].choices.items():
+                        ch.dl_in, ch.dl_out = dls[i][name]
         return mappings
 
     def _seg_sms(self, graph: DnnGraph, segments: list[Segment]):
@@ -884,20 +906,22 @@ class PimMapper:
                                   self.sm_max_regions) for seg in segments]
 
     def _solve_keys(self, graph: DnnGraph, segments: list[Segment],
-                    seg_sms, dls) -> list[tuple]:
-        """Every candidate-table key one ``_solve_sm_lm_wr`` pass touches."""
-        keys = []
+                    seg_sms, dls) -> Iterator[tuple]:
+        """Every candidate-table key one ``_solve_sm_lm_wr`` pass touches,
+        lazily: the dispatch that consumes them times their enumeration."""
         for seg, sms in zip(segments, seg_sms):
             for sm in sms:
                 for ri, region in enumerate(sm.regions):
                     for bi in sm.branches_of(ri):
                         for lname in seg.branches[bi].heavy_layers(graph):
                             din, dout = dls[lname]
-                            keys.append(self._cand_key(
+                            yield self._cand_key(
                                 graph.layer(lname), region.h_shape,
-                                region.w_shape, din, dout))
-        return keys
+                                region.w_shape, din, dout)
 
+    @trace.traced("dp_solve", cat="mapper",
+                  argspec=lambda self, graph, segments, *a, **kw:
+                  {"segments": len(segments)})
     def _solve_sm_lm_wr(self, graph: DnnGraph, segments: list[Segment],
                         dls, seg_sms=None, cand_tables=None) -> Mapping:
         hw = self.hw
@@ -1059,10 +1083,9 @@ class PimMapper:
 
     def _optimize_dl(self, graph: DnnGraph, mapping: Mapping, dls,
                      table: dict | None = None):
+        """Algorithm 1's DL pass; ``table`` is the batched layout sweep,
+        without one each candidate is costed on the scalar path."""
         hw = self.hw
-        if table is None:
-            table = (self._dl_sweep_table(graph, mapping)
-                     if self.backend == "batched" else None)
         new: dict[str, tuple[DataLayout, DataLayout]] = {}
         out_dl: dict[str, DataLayout] = {}
         for name in graph.topo_order():
@@ -1286,35 +1309,37 @@ def prefill_schedules_many(mappings: Sequence[Mapping], *,
     """
     if solver != "ilp" or backend != "scan":
         return
-    # sched key -> (shape, problems, hw); the key embeds hw, so identical
-    # sharing problems under DIFFERENT configs stay distinct memo entries
-    want: dict[tuple, tuple] = {}
-    for mapping in mappings:
-        hw = mapping.hw
-        for lname in mapping.choices:
-            args = _layer_sharing_args(mapping, lname)
-            key = _sched_key(hw, *args, solver, seed, backend)
-            if key in _SCHED_MEMO or key in want:
-                continue
-            want[key] = (args[1], _sharing_problem_list(*args), hw)
-    if not want:
-        return
     from ..engine.scheduler_opt import schedule_many
 
     def _scalars(hw: HwConfig) -> tuple:
         return (hw.link_bw_bytes, hw.cons.freq_hz,
                 hw.cons.noc_energy_pj_per_bit_hop)
 
-    # NoC-scalar triple -> (problem identity -> flat index, flat problems)
-    groups: dict[tuple, tuple[dict, list]] = {}
-    for shape, problems, hw in want.values():
-        uniq, flat = groups.setdefault(_scalars(hw), ({}, []))
-        for sets, chunk in problems:
-            pk = (shape, sets, chunk)
-            if pk not in uniq:
-                uniq[pk] = len(flat)
-                flat.append((MeshNoc(shape[0], shape[1]), sets,
-                             [chunk] * len(sets)))
+    with trace.span("sched_problems", cat="engine"):
+        # sched key -> (shape, problems, hw); the key embeds hw, so
+        # identical sharing problems under DIFFERENT configs stay distinct
+        # memo entries
+        want: dict[tuple, tuple] = {}
+        for mapping in mappings:
+            hw = mapping.hw
+            for lname in mapping.choices:
+                args = _layer_sharing_args(mapping, lname)
+                key = _sched_key(hw, *args, solver, seed, backend)
+                if key in _SCHED_MEMO or key in want:
+                    continue
+                want[key] = (args[1], _sharing_problem_list(*args), hw)
+        if not want:
+            return
+        # NoC-scalar triple -> (problem identity -> flat index, problems)
+        groups: dict[tuple, tuple[dict, list]] = {}
+        for shape, problems, hw in want.values():
+            uniq, flat = groups.setdefault(_scalars(hw), ({}, []))
+            for sets, chunk in problems:
+                pk = (shape, sets, chunk)
+                if pk not in uniq:
+                    uniq[pk] = len(flat)
+                    flat.append((MeshNoc(shape[0], shape[1]), sets,
+                                 [chunk] * len(sets)))
     with trace.span("prefill_schedules", cat="engine",
                     mappings=len(mappings), missing=len(want),
                     problems=sum(len(f) for _, f in groups.values()),

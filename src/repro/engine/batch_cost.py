@@ -40,6 +40,7 @@ from ..core.costmodel import (MAC_ENERGY_PJ, PartCost, _sram_pj_per_bit,
 from ..core.hardware import HwConfig
 from ..core.ir import Layer
 from ..core.layout import DataLayout
+from ..obs import trace
 from ..obs.trace import traced
 from ..runtime import x64
 
@@ -569,12 +570,13 @@ def batch_part_cost(configs: Sequence[HwConfig],
             res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
                               psum_bits=cons.psum_bits,
                               dram_row_miss=cons.dram_row_miss_cycles)
-            for k, v in res.items():
-                # this per-chunk pull IS the dispatch boundary: chunks must
-                # land on host to be concatenated, and each pull overlaps
-                # the next chunk's dispatch
-                # pimlint: disable-next-line=host-sync -- sanctioned per-chunk boundary pull
-                outs.setdefault(k, []).append(np.asarray(v))
+            with trace.span("device_wait", cat="engine", what="batch_cost"):
+                for k, v in res.items():
+                    # this per-chunk pull IS the dispatch boundary: chunks
+                    # must land on host to be concatenated, and each pull
+                    # overlaps the next chunk's dispatch
+                    # pimlint: disable-next-line=host-sync -- sanctioned per-chunk boundary pull
+                    outs.setdefault(k, []).append(np.asarray(v))
     res = {k: np.concatenate(v, axis=0)[:n] for k, v in outs.items()}
     return _finalize_result(res, configs, specs, cons)
 
@@ -683,7 +685,8 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
                           psum_bits=cons.psum_bits,
                           dram_row_miss=cons.dram_row_miss_cycles,
                           paired=True)
-    res = {k: np.asarray(v)[:, :n_real] for k, v in res.items()}
+    with trace.span("device_wait", cat="engine", what="batch_cost"):
+        res = {k: np.asarray(v)[:, :n_real] for k, v in res.items()}
     return _finalize_result(res, configs[:n_real], specs[:n_real], cons)
 
 

@@ -97,8 +97,9 @@ class PendingPairedCost:
         """Block on the device rows (once) and return ``[n]`` seconds."""
         if self._row is None:
             out = np.empty(self.n, np.float64)
-            for idxs, dev, n_real in self._parts:
-                out[idxs] = np.asarray(dev)[0, :n_real] / self._freq
+            with trace.span("device_wait", cat="engine", what="batch_cost"):
+                for idxs, dev, n_real in self._parts:
+                    out[idxs] = np.asarray(dev)[0, :n_real] / self._freq
             self._row = out
             self._parts = None
         return self._row

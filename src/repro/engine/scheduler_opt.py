@@ -50,7 +50,7 @@ import numpy as np
 from ..core.noc import MeshNoc
 from ..core.scheduler import (ScheduleResult, _all_transfers, _finish,
                               _initial_cycles, _solve_exact)
-from ..obs import metrics, trace
+from ..obs import trace
 from ..runtime import native_kernels, x64
 from .jit_registry import register_jits
 from .tuner_train import pow2_bucket
@@ -492,8 +492,6 @@ def _pack_solve(setups: list[_Setup], *, rounds: int, moves_per_round: int,
     chains = len(setups[0].inits)
     _, e_pad = _mesh_pads(noc, pad_shapes)
     rows = len(setups) * chains
-    metrics.METRICS.histogram("scheduler.bucket_fill").observe(rows / r_pad)
-    metrics.METRICS.counter("scheduler.padded_rows").inc(r_pad - rows)
     cycles0 = np.zeros((r_pad, s_pad, n_pad), dtype=np.int32)
     lens = np.zeros((r_pad, s_pad), dtype=np.int32)
     weights = np.zeros((r_pad, s_pad))
@@ -509,18 +507,19 @@ def _pack_solve(setups: list[_Setup], *, rounds: int, moves_per_round: int,
                 weights[row, si] = (len(cyc) - 1) * st.chunks[si]
             loads0[row, :e] = noc.link_loads_np(
                 _all_transfers(init, list(st.chunks)))
-    # keys feed the host-side packed arrays: one pull per bucket, before
-    # the scan dispatch
-    # pimlint: disable-next-line=host-sync -- sanctioned per-bucket key pull
-    keys[:rows] = np.asarray(_fold_keys(
+    folded = _fold_keys(
         jax.device_put(np.array(
             [st.seed_eff for st in setups for _ in range(chains)],
             dtype=np.uint32)),
         jax.device_put(np.array(
             [st.digest for st in setups for _ in range(chains)],
             dtype=np.uint32)),
-        jax.device_put(np.arange(rows, dtype=np.uint32) % chains)),
-        dtype=np.uint32)
+        jax.device_put(np.arange(rows, dtype=np.uint32) % chains))
+    # keys feed the host-side packed arrays: one pull per bucket, before
+    # the scan dispatch
+    with trace.span("device_wait", cat="engine", what="fold_keys"):
+        # pimlint: disable-next-line=host-sync -- sanctioned per-bucket key pull
+        keys[:rows] = np.asarray(folded, dtype=np.uint32)
     for row in range(rows, r_pad):   # padded rows: burn a copy of row 0
         cycles0[row], lens[row] = cycles0[0], lens[0]
         weights[row], loads0[row], keys[row] = (weights[0], loads0[0],
@@ -535,8 +534,9 @@ def _pack_solve(setups: list[_Setup], *, rounds: int, moves_per_round: int,
             jax.device_put(weights), jax.device_put(loads0),
             jax.device_put(keys), inc,
             rounds=rounds, n_moves=moves_per_round, use_pallas=use_pallas)
-    # pimlint: disable-next-line=host-sync -- the one result pull per bucket
-    out_cycles = np.asarray(out_cycles)
+    with trace.span("device_wait", cat="engine", what="scan_solve"):
+        # pimlint: disable-next-line=host-sync -- the one result pull per bucket
+        out_cycles = np.asarray(out_cycles)
     results = []
     for p, st in enumerate(setups):
         per_chain = []

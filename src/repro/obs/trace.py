@@ -3,24 +3,34 @@
 A :class:`Tracer` collects *complete* (``ph="X"``) trace events — one per
 host-side span — into an in-memory list and serializes them as Chrome trace
 event format JSON (load the file in ``chrome://tracing`` or Perfetto).  The
-DSE stack is instrumented at two levels:
+DSE stack is instrumented at three levels:
 
-* **phase spans** (``cat="dse"``): ``propose`` / ``map`` / ``schedule`` /
-  ``fit`` / ``evaluate`` / ``checkpoint`` emitted by ``run_dse`` /
-  ``WorkloadEvaluator`` / ``Campaign``, one timeline row (tid) per strategy
-  thread;
-* **engine dispatch spans** (``cat="engine"``): ``batch_cost``,
-  ``map_many``, ``schedule_many``, ``fit_filter`` / ``fit_dkl``,
-  ``score_candidates`` — each also wrapped in a
-  :class:`jax.profiler.TraceAnnotation` so the host spans line up with XLA
-  device traces when ``jax.profiler.trace`` is active.
+* **phase spans** (``cat="dse"``): ``iteration`` / ``propose`` / ``map`` /
+  ``fit`` / ``evaluate`` / ``accounting`` / ``checkpoint`` emitted by
+  ``run_dse`` / ``WorkloadEvaluator`` / ``Campaign``, one timeline row (tid)
+  per strategy thread;
+* **mapper phase spans** (``cat="mapper"``): ``cand_dispatch`` /
+  ``cand_build`` (candidate tables), ``dp_solve`` (Algorithm 2) and
+  ``dl_dispatch`` / ``dl_optimize`` (the DL pass), each closed before the
+  phase generator yields;
+* **engine spans** (``cat="engine"``): ``batch_cost``, ``dispatch_paired``,
+  ``map_many`` / ``map_wave``, ``overlap_drain``, ``sched_problems``,
+  ``prefill_schedules``, ``schedule_many`` / ``schedule``, ``fit_filter`` /
+  ``fit_dkl``, ``score_candidates``, and ``device_wait`` around each
+  blocking pull of device results.
+
+Every span is also a :class:`jax.profiler.TraceAnnotation`, on the
+profiler's clock: while ``jax.profiler.trace`` is active a span's ``ts``
+and its annotation differ by one constant offset, so the host spans line
+up with the XLA device trace.
 
 Tracing is process-global and opt-in: :func:`install` (or the
 :func:`activate` context manager) sets the active tracer; the module-level
 :func:`span` helper is the single hot-path entry point and collapses to a
 shared no-op context manager when no tracer is installed, so the disabled
-path costs one global read + one singleton ``with`` (measured <1% on
-``benchmarks/engine_throughput``).
+path costs one global read + one singleton ``with`` per span site (the
+decorator form: one global read and the plain call).  What tracing costs
+when it is on is measured on the chip, and recorded in ``PERF.md``.
 
 Span ``args`` carry the batch size / pow2 bucket key / cache outcome of the
 dispatch; the context manager yields a mutable dict, so outcomes discovered
@@ -111,16 +121,18 @@ class Tracer:
         Yields the ``args`` dict — mutate it to attach outcomes (cache
         hit/miss, bucket keys) discovered while the span is open.
         """
-        t0 = self._now_us()
         ann = TraceAnnotation(name) if TraceAnnotation is not None else None
         if ann is not None:
             ann.__enter__()
+        # the span's edges sit right inside the annotation's, so the two
+        # differ by the clocks' offset alone
+        t0 = self._now_us()
         try:
             yield args
         finally:
+            t1 = self._now_us()
             if ann is not None:
                 ann.__exit__(None, None, None)
-            t1 = self._now_us()
             ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
                   "dur": t1 - t0, "pid": _PID, "tid": self._tid(),
                   "args": args}
@@ -140,12 +152,19 @@ class Tracer:
             return list(self._events)
 
     def to_chrome(self) -> dict:
-        """Chrome trace event format object (metadata first, spans by ts)."""
+        """Chrome trace event format object (metadata first, spans by ts).
+
+        ``otherData.origin_perf_counter_ns`` is the tracer's zero on the
+        host's monotonic clock.  Every span is also a profiler annotation,
+        so one span found in both traces gives the single offset that lays
+        this file over a ``jax.profiler`` device trace.
+        """
         evs = self.events()
         meta = [e for e in evs if e["ph"] == "M"]
         rest = sorted((e for e in evs if e["ph"] != "M"),
                       key=lambda e: e["ts"])
-        return {"traceEvents": meta + rest, "displayTimeUnit": "ms"}
+        return {"traceEvents": meta + rest, "displayTimeUnit": "ms",
+                "otherData": {"origin_perf_counter_ns": self._t0_ns}}
 
     def save(self, path: str | Path) -> Path:
         path = Path(path)

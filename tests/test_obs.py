@@ -136,6 +136,40 @@ def test_span_threads_get_distinct_rows():
     assert {e["args"]["name"] for e in names} == {"w0", "w1"}
 
 
+
+def test_spans_share_one_clock_with_the_profiler(tmp_path):
+    """Each span's ``ts`` and its annotation in the profiler's trace differ
+    by one constant offset, so the Chrome file lays over a device trace."""
+    import jax
+    before = time.perf_counter_ns()
+    t = Tracer()
+    after = time.perf_counter_ns()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.activate(t):
+            for i in range(5):
+                with trace.span(f"clock_probe_{i}"):
+                    time.sleep(0.004)
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    starts = {}
+    for plane in jax.profiler.ProfileData.from_file(str(files[-1])).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("clock_probe_"):
+                    starts[ev.name] = ev.start_ns
+    spans = [e for e in t.events() if e["ph"] == "X"]
+    assert {e["name"] for e in spans} == set(starts)
+    offsets = [starts[e["name"]] - e["ts"] * 1e3 for e in spans]
+    assert max(offsets) - min(offsets) < 0.1e6      # 0.1 ms
+    # the origin on the host's monotonic clock travels with the file
+    origin = t.to_chrome()["otherData"]["origin_perf_counter_ns"]
+    assert before <= origin <= after
+
+
 # -- metrics -----------------------------------------------------------------
 
 def test_metrics_registry_instruments():
