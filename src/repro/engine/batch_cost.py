@@ -7,10 +7,12 @@ operation-for-operation in float64 (:func:`repro.runtime.x64`), so the
 batched result matches the scalar reference within 1e-6 relative tolerance —
 including the chosen tiling and loop order — which the engine tests enforce.
 
-Host-side preprocessing builds, per part-layer, the same power-of-two tiling
-candidate grid the scalar model searches (padded to a common ``T`` with a
-validity mask); the per-candidate ``max(compute, dram)`` bottleneck and the
-masked first-argmin over candidates are one f64 ``jnp`` reduction, the same
+Host-side preprocessing packs one row of layer dims and DRAM-layout fields
+per part-layer; the program derives from those dims the same power-of-two
+tiling candidate grid the scalar model searches (padded to a common ``T``
+and masked past each row's grid), so no ``[L, T]`` array crosses to the
+device.  The per-candidate ``max(compute, dram)`` bottleneck and the masked
+first-argmin over candidates are one f64 ``jnp`` reduction, the same
 expression on every backend (Mosaic compiles no f64 Pallas kernel).
 
 Batch axes:
@@ -35,8 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.costmodel import (MAC_ENERGY_PJ, PartCost, _sram_pj_per_bit,
-                              _tile_candidates)
+from ..core.costmodel import MAC_ENERGY_PJ, PartCost, _sram_pj_per_bit
 from ..core.hardware import HwConfig
 from ..core.ir import Layer
 from ..core.layout import DataLayout
@@ -61,22 +62,22 @@ class PartSpec:
 # ---------------------------------------------------------------------------
 
 
-@lru_cache(maxsize=4096)
-def _candidate_grid(layer: Layer):
-    """The exact candidate tiling grid of ``part_layer_cost`` (same order).
+#: the axes of the candidate tiling grid, in ``part_layer_cost``'s meshgrid
+#: order, each with its ``_tile_candidates`` cap (``Q`` has one candidate,
+#: ``Q`` itself, when ``Q <= 64``)
+_GRID_AXES = (("B", 4), ("K", 7), ("C", 7), ("P", 7), ("Q", 4))
 
-    Cached (layers repeat massively across mapper candidate sweeps); callers
-    treat the returned array as read-only.
-    """
-    tks = np.array(_tile_candidates(layer.K), dtype=np.int64)
-    tcs = np.array(_tile_candidates(layer.C), dtype=np.int64)
-    tps = np.array(_tile_candidates(layer.P), dtype=np.int64)
-    tqs = np.array([layer.Q], dtype=np.int64) if layer.Q <= 64 else \
-        np.array(_tile_candidates(layer.Q, cap=4), dtype=np.int64)
-    tbs = np.array(_tile_candidates(layer.B, cap=4), dtype=np.int64)
-    tb, tk, tc, tp, tq = [a.reshape(-1) for a in
-                          np.meshgrid(tbs, tks, tcs, tps, tqs, indexing="ij")]
-    return np.stack([tb, tk, tc, tp, tq], axis=0)  # [5, T_l]
+
+def _n_candidates(dim: int, cap: int) -> int:
+    """``len(_tile_candidates(dim, cap))`` in closed form."""
+    return min((dim - 1).bit_length() + 1, cap)
+
+
+def _grid_size(layer: Layer) -> int:
+    """Size of ``part_layer_cost``'s candidate tiling grid, in closed form."""
+    q = 1 if layer.Q <= 64 else _n_candidates(layer.Q, 4)
+    return (_n_candidates(layer.B, 4) * _n_candidates(layer.K, 7)
+            * _n_candidates(layer.C, 7) * _n_candidates(layer.P, 7) * q)
 
 
 def _dl_fields(dl: DataLayout, channels: int) -> tuple[bool, int, int]:
@@ -100,39 +101,40 @@ _FLOAT_KEYS = ("macs", "w_vals", "i_vals", "o_vals")
 @lru_cache(maxsize=65536)
 def _spec_static(layer: Layer):
     """The DL-independent row of one part-layer (mapper sweeps repeat them)."""
-    g = _candidate_grid(layer)
-    tb, tk, tc, tp, tq = g
-    th = (tp - 1) * layer.stride + layer.HK
-    tw = (tq - 1) * layer.stride + layer.WK
     ints = tuple(getattr(layer, k) for k in _INT_KEYS[:10])
     floats = (float(layer.macs), float(layer.weight_count),
               float(layer.B * layer.C * layer.H * layer.W),
               float(layer.B * layer.K * layer.P * layer.Q))
-    return g, int(np.argmin(tb * tc * th * tw)), ints, layer.is_heavy, floats
+    return _grid_size(layer), ints, layer.is_heavy, floats
+
+
+def _t_bucket(layer: Layer) -> int:
+    """The candidate-axis bucket of a part-layer (floor 128, power of two).
+
+    Per-spec, so a spec always lands in the same ``T`` program whatever
+    batch it arrives in; padding tiny grids up is cheaper than another
+    dispatch round-trip.
+    """
+    return _next_pow2(max(128, _spec_static(layer)[0]))
 
 
 def _prep_specs(specs: Sequence[PartSpec], *, t_pad: int | None = None):
-    """Pack L part-layer specs into padded numpy arrays.
+    """Pack L part-layer specs into ``[L]`` numpy arrays, one per field.
 
-    ``t_pad`` fixes the candidate axis to a caller-chosen bucket width
-    (padding is masked invalid) so spec-chunked callers compile one XLA
-    program per ``(L, T-bucket)`` pair instead of one per distinct
-    tiling-grid size.
+    Returns ``(lay, t)``: the per-row layer and layout fields, and the
+    candidate-axis width ``t`` for ``_batch_cost``, which derives each
+    row's tiling grid from its dims on the device.  ``t_pad`` fixes that
+    width to a caller-chosen bucket (padding is masked invalid) so
+    spec-chunked callers compile one XLA program per ``(L, T-bucket)``
+    pair instead of one per distinct tiling-grid size.
     """
     statics = [_spec_static(s.layer) for s in specs]
-    t_max = max(st[0].shape[1] for st in statics)
+    t_max = max(st[0] for st in statics)
     if t_pad is not None:
         assert t_pad >= t_max, "t_pad below the largest candidate grid"
         t_max = t_pad
-    L = len(specs)
-    tiles = np.ones((5, L, t_max), dtype=np.int64)
-    valid = np.zeros((L, t_max), dtype=bool)
-    int_rows, flag_rows, float_rows, fallback = [], [], [], []
-    for i, (s, (g, fb, ints, heavy, floats)) in enumerate(zip(specs, statics)):
-        t = g.shape[1]
-        tiles[:, i, :t] = g
-        valid[i, :t] = True
-        fallback.append(fb)
+    int_rows, flag_rows, float_rows = [], [], []
+    for s, (_, ints, heavy, floats) in zip(specs, statics):
         in_bhwc, gi, ali = _dl_fields(s.dl_in, s.layer.C)
         out_bhwc, go, alo = _dl_fields(s.dl_out, s.layer.K)
         int_rows.append(ints + (gi, ali, go, alo))
@@ -147,9 +149,7 @@ def _prep_specs(specs: Sequence[PartSpec], *, t_pad: int | None = None):
              for j, k in enumerate(_FLAG_KEYS)}
     floats = {k: np.ascontiguousarray(float_arr[:, j])
               for j, k in enumerate(_FLOAT_KEYS)}
-    return {"tiles": tiles, "valid": valid,
-            "fallback": np.array(fallback, dtype=np.int64),
-            **ints, **flags, **floats}
+    return {**ints, **flags, **floats}, t_max
 
 
 def _prep_configs(configs: Sequence[HwConfig]):
@@ -262,14 +262,65 @@ def _access_cost(fmap, tb, tc, th, tw, is_bhwc, group, align,
     return bursts, rows
 
 
-@partial(jax.jit, static_argnames=("data_bits", "psum_bits", "dram_row_miss",
-                                   "paired"))
-def _batch_cost(cfg, lay, *, data_bits: int, psum_bits: int,
+def _tile_grid(lay, t: int):
+    """Each row's candidate tiling grid, derived from its dims: [L, t].
+
+    Row ``l`` holds ``_tile_candidates`` of its ``B, K, C, P, Q`` in
+    ``part_layer_cost``'s order (``np.meshgrid(..., indexing="ij")``
+    flattened in C order): index ``i`` splits into mixed-radix digits over
+    the per-axis candidate counts, ``Q`` fastest.  Candidate ``j`` of a dim
+    ``d`` with ``n = (d - 1).bit_length()`` powers of two below it is
+    ``2 ** (j + off)`` while ``j + off < n``, else ``d``, where ``off``
+    drops the smallest beyond the cap.
+
+    Returns the int64 tile arrays ``tb, tk, tc, tp, tq`` and the input
+    window ``th, tw``, the mask ``valid`` (``i`` below the row's grid
+    size) and ``fallback`` ([L, 1]): the scalar model's choice when no
+    tiling fits, the first smallest input tile of the grid.
+    """
+    i32 = jnp.int32
+    n, count = {}, {}
+    for name, cap in _GRID_AXES:
+        n[name] = 32 - jax.lax.clz(lay[name].astype(i32) - 1)
+        count[name] = jnp.minimum(n[name] + 1, cap)
+    count["Q"] = jnp.where(lay["Q"] <= 64, 1, count["Q"])
+    place, size = {}, jnp.ones_like(count["B"])
+    for name, _ in reversed(_GRID_AXES):
+        place[name], size = size, size * count[name]
+    rem = jnp.arange(t, dtype=i32)[None, :]
+    g = {}
+    for name, cap in _GRID_AXES:
+        # a digit is below its cap, so comparisons find it: an integer
+        # division compiles to a long sequence for the TPU
+        p = place[name][:, None]
+        j = sum((rem >= m * p).astype(i32) for m in range(1, cap))
+        rem = rem - j * p
+        k = j + (n[name] + 1 - count[name])[:, None]
+        g["t" + name.lower()] = jnp.where(k < n[name][:, None],
+                                          jnp.left_shift(1, k),
+                                          lay[name][:, None])
+    stride = lay["stride"][:, None]
+    g["th"] = (g["tp"] - 1) * stride + lay["HK"][:, None]
+    g["tw"] = (g["tq"] - 1) * stride + lay["WK"][:, None]
+    g["valid"] = jnp.arange(t, dtype=i32)[None, :] < size[:, None]
+    in_tile = jnp.where(g["valid"], g["tb"] * g["tc"] * g["th"] * g["tw"],
+                        jnp.iinfo(jnp.int64).max)
+    g["fallback"] = jnp.argmin(in_tile, axis=-1, keepdims=True)
+    # materialized once, as host inputs would be: fused into each consumer
+    # instead, the integer work makes the v5e compile half as long again
+    return jax.lax.optimization_barrier(g)
+
+
+@partial(jax.jit, static_argnames=("t_pad", "data_bits", "psum_bits",
+                                   "dram_row_miss", "paired"))
+def _batch_cost(cfg, lay, *, t_pad: int, data_bits: int, psum_bits: int,
                 dram_row_miss: int, paired: bool = False):
     """Score every (config, part-layer, candidate-tiling) point.
 
-    ``cfg`` arrays are [N], ``lay`` per-layer arrays [L] and tile arrays
-    [5, L, T].  Returns per-(config, layer) selections, all [N, L].
+    ``cfg`` arrays are [N] and ``lay`` arrays [L], one row of dims and
+    layout fields per part-layer; the candidate tiling grid is derived
+    here from the dims (:func:`_tile_grid`), ``t_pad`` candidates wide.
+    Returns per-(config, layer) selections, all [N, L].
 
     ``paired=True`` aligns the config axis WITH the layer axis (``cfg``
     arrays are [L], one config per part-layer): the result is the [1, L]
@@ -293,19 +344,18 @@ def _batch_cost(cfg, lay, *, data_bits: int, psum_bits: int,
     dbytes = data_bits // 8
     pbytes = psum_bits // 8
 
-    TB, TK, TC, TP, TQ = [lay["tiles"][i][None] for i in range(5)]  # [1,L,T]
-    stride, HK, WK = l3("stride"), l3("HK"), l3("WK")
-    TH = (TP - 1) * stride + HK
-    TW = (TQ - 1) * stride + WK
+    g = {k: v[None] for k, v in _tile_grid(lay, t_pad).items()}  # [1, L, T]
+    TB, TK, TC, TP, TQ = g["tb"], g["tk"], g["tc"], g["tp"], g["tq"]
+    TH, TW = g["th"], g["tw"]
+    HK, WK = l3("HK"), l3("WK")
 
     # ---- capacity filter (int64, exactly as the scalar model) --------------
     fits = ((TB * TC * TH * TW * dbytes * 2 <= c3("ibuf_kib") * 1024)
             & (TK * TC * HK * WK * dbytes * 2 <= c3("wbuf_kib") * 1024)
             & (TB * TK * TP * TQ * pbytes <= c3("obuf_kib") * 1024))
-    eligible = fits & lay["valid"][None]
+    eligible = fits & g["valid"]
     any_fit = eligible.any(axis=-1, keepdims=True)
-    t = TB.shape[-1]
-    onehot = (jnp.arange(t)[None, None, :] == l3("fallback"))
+    onehot = jnp.arange(t_pad)[None, None, :] == g["fallback"]
     mask = jnp.where(any_fit, eligible, onehot)
 
     # ---- float views -------------------------------------------------------
@@ -373,7 +423,7 @@ def _batch_cost(cfg, lay, *, data_bits: int, psum_bits: int,
 
     # ---- inner reduction: bottleneck + masked first-argmin ----------------
     n, l_dim = compute_cycles.shape[0], compute_cycles.shape[1]
-    shape3 = (n, l_dim, t)
+    shape3 = (n, l_dim, t_pad)
     cand = jnp.where(mask, jnp.maximum(compute_cycles, dram_cycles), jnp.inf)
     total = jnp.min(cand, axis=-1)
     # first occurrence of the min, matching np.argmin in the scalar model
@@ -516,15 +566,10 @@ def batch_part_cost(configs: Sequence[HwConfig],
     t_pad = None
     if spec_chunk is not None:
         # group by candidate-axis bucket first: a mixed batch otherwise pads
-        # every small tiling grid to the largest one in the batch.  The
-        # bucket key is per-spec (floor 128: padding tiny grids up is cheaper
-        # than another dispatch round-trip), so a spec always lands in the
-        # same (spec_chunk, T) program whatever batch it arrives in.
+        # every small tiling grid to the largest one in the batch
         buckets = {}
         for i, s in enumerate(specs):
-            buckets.setdefault(
-                _next_pow2(max(128, _candidate_grid(s.layer).shape[1])),
-                []).append(i)
+            buckets.setdefault(_t_bucket(s.layer), []).append(i)
         t_pad = max(buckets)
         if len(buckets) > 1:
             merged: dict[str, np.ndarray] = {}
@@ -552,7 +597,7 @@ def batch_part_cost(configs: Sequence[HwConfig],
         merged = {f: np.concatenate([getattr(r, f)[:, :n] for r, n in blocks],
                                     axis=1) for f in fields}
         return BatchCostResult(configs=list(configs), specs=specs, **merged)
-    lay_np = _prep_specs(specs, t_pad=t_pad)
+    lay_np, t = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
 
     n = len(configs)
@@ -567,7 +612,7 @@ def batch_part_cost(configs: Sequence[HwConfig],
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         for s in range(0, n + pad, chunk):
             cfg = {k: jnp.asarray(v[s:s + chunk]) for k, v in cfg_np.items()}
-            res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
+            res = _batch_cost(cfg, lay, t_pad=t, data_bits=cons.data_bits,
                               psum_bits=cons.psum_bits,
                               dram_row_miss=cons.dram_row_miss_cycles)
             with trace.span("device_wait", cat="engine", what="batch_cost"):
@@ -639,14 +684,10 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
         raise ValueError("paired costing needs len(configs) == len(specs)")
     if not specs:
         raise ValueError("need at least one (config, spec) pair")
-    # same per-spec T-bucket key as batch_part_cost's spec-chunked path: a
-    # pair always lands in the same (pair-bucket, T) program whatever batch
-    # it arrives in
+    # same per-spec T-bucket key as batch_part_cost's spec-chunked path
     buckets: dict[int, list[int]] = {}
     for i, s in enumerate(specs):
-        buckets.setdefault(
-            _next_pow2(max(128, _candidate_grid(s.layer).shape[1])),
-            []).append(i)
+        buckets.setdefault(_t_bucket(s.layer), []).append(i)
     if len(buckets) > 1:
         merged: dict[str, np.ndarray] = {}
         for tb in sorted(buckets):
@@ -676,12 +717,12 @@ def batch_part_cost_paired(configs: Sequence[HwConfig],
     if n_pad > n_real:  # pow2 pair-bucket: bounded XLA program count
         configs = configs + [configs[-1]] * (n_pad - n_real)
         specs = specs + [specs[-1]] * (n_pad - n_real)
-    lay_np = _prep_specs(specs, t_pad=t_pad)
+    lay_np, t = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
     with x64():
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         cfg = {k: jnp.asarray(v) for k, v in cfg_np.items()}
-        res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
+        res = _batch_cost(cfg, lay, t_pad=t, data_bits=cons.data_bits,
                           psum_bits=cons.psum_bits,
                           dram_row_miss=cons.dram_row_miss_cycles,
                           paired=True)

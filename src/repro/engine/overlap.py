@@ -45,8 +45,8 @@ import numpy as np
 
 from ..obs import trace
 from ..runtime import x64
-from .batch_cost import (PartSpec, _batch_cost, _candidate_grid, _next_pow2,
-                         _prep_configs, _prep_specs)
+from .batch_cost import (PartSpec, _batch_cost, _next_pow2, _prep_configs,
+                         _prep_specs, _t_bucket)
 from .jit_registry import register_jits
 
 _STATE = threading.local()
@@ -106,22 +106,26 @@ class PendingPairedCost:
 
 
 def _dispatch_block(configs, specs, idxs, t_pad, spec_chunk):
-    """One ``_batch_cost`` leaf — same padding/programs as the serial path."""
+    """One ``_batch_cost`` leaf — same padding/programs as the serial path.
+
+    Returns the pending part and the bytes of host arrays it handed over.
+    """
     n_real = len(specs)
     n_pad = min(spec_chunk, _next_pow2(max(128, n_real)))
     if n_pad > n_real:
         configs = configs + [configs[-1]] * (n_pad - n_real)
         specs = specs + [specs[-1]] * (n_pad - n_real)
-    lay_np = _prep_specs(specs, t_pad=t_pad)
+    lay_np, t = _prep_specs(specs, t_pad=t_pad)
     cfg_np, cons = _prep_configs(configs)
     with x64():
         lay = {k: jnp.asarray(v) for k, v in lay_np.items()}
         cfg = {k: jnp.asarray(v) for k, v in cfg_np.items()}
-        res = _batch_cost(cfg, lay, data_bits=cons.data_bits,
+        res = _batch_cost(cfg, lay, t_pad=t, data_bits=cons.data_bits,
                           psum_bits=cons.psum_bits,
                           dram_row_miss=cons.dram_row_miss_cycles,
                           paired=True)
-    return idxs, res["total_cycles"], n_real
+    nbytes = sum(v.nbytes for v in (*lay_np.values(), *cfg_np.values()))
+    return (idxs, res["total_cycles"], n_real), nbytes
 
 
 def dispatch_paired_latency(configs, specs, *, spec_chunk: int = 1024
@@ -141,19 +145,20 @@ def dispatch_paired_latency(configs, specs, *, spec_chunk: int = 1024
         raise ValueError("need at least one (config, spec) pair")
     buckets: dict[int, list[int]] = {}
     for i, s in enumerate(specs):
-        buckets.setdefault(
-            _next_pow2(max(128, _candidate_grid(s.layer).shape[1])),
-            []).append(i)
-    parts = []
+        buckets.setdefault(_t_bucket(s.layer), []).append(i)
+    parts, nbytes = [], 0
     with trace.span("dispatch_paired", cat="engine",
-                    pairs=len(specs), buckets=len(buckets)):
+                    pairs=len(specs), buckets=len(buckets)) as sp:
         for tb in sorted(buckets):
             idxs = buckets[tb]
             for s in range(0, len(idxs), spec_chunk):
                 blk = idxs[s:s + spec_chunk]
-                parts.append(_dispatch_block(
+                part, n = _dispatch_block(
                     [configs[i] for i in blk], [specs[i] for i in blk],
-                    np.asarray(blk, np.intp), tb, spec_chunk))
+                    np.asarray(blk, np.intp), tb, spec_chunk)
+                parts.append(part)
+                nbytes += n
+        sp["bytes"] = nbytes
     pending = PendingPairedCost(len(specs), parts, configs[0].cons.freq_hz)
     if not overlap_enabled():
         pending.latency_row()

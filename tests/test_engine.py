@@ -18,10 +18,14 @@ from repro.core.workloads import googlenet
 from repro.engine import (Campaign, EvalCache, ParetoFront, ParetoPoint,
                           PartSpec, batch_area_mm2, batch_max_link_load,
                           batch_part_cost, graph_digest, hw_digest)
+from repro.engine.batch_cost import _t_bucket, batch_part_cost_paired
 
-RTOL = 1e-6
 COST_FIELDS = ("latency_s", "energy_pj", "compute_s", "dram_s", "dram_bytes",
                "e_mac_pj", "e_sram_pj", "e_dram_pj")
+# XLA's CPU backend contracts the SRAM energy's products and sums into fused
+# multiply-adds, which the scalar NumPy model does not: these two differ
+# from it by rounding alone, every other field is bitwise equal
+ROUNDED_FIELDS = ("energy_pj", "e_sram_pj")
 
 
 def _specs():
@@ -46,19 +50,46 @@ def _specs():
 # ---------------------------------------------------------------------------
 
 
-def test_batched_matches_scalar_on_randomized_configs():
+def _googlenet_specs():
+    """GoogLeNet-224's heavy layers (every T-bucket) and one aux layer."""
+    net = googlenet(1)
+    layers = [l for l in net.layers if l.is_heavy]
+    layers.append(next(l for l in net.layers if not l.is_heavy))
+    dls = [DataLayout("BCHW", 1), DataLayout("BCHW", 8), DataLayout("BHWC"),
+           DataLayout("BCHW", 16)]
+    return [PartSpec(l, dls[i % 4], dls[(i + 1) % 4])
+            for i, l in enumerate(layers)]
+
+
+@pytest.mark.parametrize("case", ["mixed", "googlenet_grid",
+                                  "googlenet_paired"])
+def test_batched_matches_scalar_on_randomized_configs(case):
     rng = np.random.default_rng(42)
     configs = [PAPER_BEST, PAPER_4X4, PAPER_16X16] + sample_configs(6, rng)
-    specs = _specs()
-    res = batch_part_cost(configs, specs, chunk=4)
+    paired = case == "googlenet_paired"
+    if case == "mixed":
+        specs = _specs()
+        res = batch_part_cost(configs, specs, chunk=4)
+    else:
+        specs = _googlenet_specs()
+        assert {_t_bucket(s.layer) for s in specs} >= {128, 256, 512, 1024}
+        if paired:
+            res = batch_part_cost_paired(
+                [c for c in configs for _ in specs], specs * len(configs))
+        else:
+            res = batch_part_cost(configs, specs, chunk=4, spec_chunk=64)
     for i, cfg in enumerate(configs):
         for j, s in enumerate(specs):
             ref = part_layer_cost(cfg, s.layer, s.dl_in, s.dl_out)
-            got = res.part_cost(i, j)
+            got = (res.part_cost(0, i * len(specs) + j) if paired
+                   else res.part_cost(i, j))
             for f in COST_FIELDS:
                 a, b = getattr(ref, f), getattr(got, f)
-                assert a == pytest.approx(b, rel=RTOL, abs=1e-30), \
-                    (cfg.as_tuple(), s.layer.name, f)
+                if f in ROUNDED_FIELDS:
+                    assert abs(a - b) <= 2 * np.spacing(abs(a)), \
+                        (cfg.as_tuple(), s.layer.name, f)
+                else:
+                    assert a == b, (cfg.as_tuple(), s.layer.name, f)
             assert ref.tiling == got.tiling, (cfg.as_tuple(), s.layer.name)
             assert ref.loop_order == got.loop_order
 

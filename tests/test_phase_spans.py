@@ -22,9 +22,11 @@ import pytest
 from repro.core import mapper as mapper_mod
 from repro.core.dse import WorkloadEvaluator
 from repro.core.hardware import PAPER_4X4, PAPER_BEST, HwConfig
+from repro.core.layout import DataLayout
 from repro.core.mapper import PimMapper, clear_mapper_caches
 from repro.core.workloads import googlenet
 from repro.engine import scheduler_opt
+from repro.engine.batch_cost import PartSpec, _prep_configs, _prep_specs
 from repro.obs import trace
 from repro.obs.trace import Tracer
 
@@ -39,7 +41,7 @@ ARGS = {"cand_dispatch": {"keys", "built"}, "cand_build": {"tables"},
         "dp_solve": {"segments"}, "dl_dispatch": {"specs"},
         "dl_optimize": {"specs"}, "device_wait": {"what"},
         "sched_problems": set(), "accounting": set(),
-        "dispatch_paired": {"pairs"}, "schedule": {"problems"}}
+        "dispatch_paired": {"pairs", "bytes"}, "schedule": {"problems"}}
 
 
 class StackTracer(Tracer):
@@ -141,9 +143,20 @@ def test_every_phase_span_is_emitted_with_its_arguments(runs, path):
             assert keys <= set(s["args"]), (path, name, s["args"])
     waits = {s["args"]["what"] for s in spans if s["name"] == "device_wait"}
     assert waits == {"batch_cost", "fold_keys", "scan_solve"}
+    # the host arrays of one padded pair: per-row layer fields and the
+    # pair's config fields, no [L, T] tiling grid
+    lay, _ = _prep_specs([PartSpec(googlenet(1).layers[0],
+                                   DataLayout("BHWC"), DataLayout("BHWC"))])
+    cfg, _ = _prep_configs([PAPER_BEST])
+    pair_bytes = sum(v.nbytes for v in (*lay.values(), *cfg.values()))
     for s in spans:
         if s["name"] == "dispatch_paired":
             assert s["args"]["pairs"] > 0
+            # each block pads its pairs to a power of two, floor 128
+            n = s["args"]["bytes"] // pair_bytes
+            assert n * pair_bytes == s["args"]["bytes"], (path, s["args"])
+            assert s["args"]["pairs"] <= n, (path, s["args"])
+            assert n < 2 * s["args"]["pairs"] + 128 * s["args"]["buckets"]
         if s["name"] == "cand_dispatch":
             assert 0 <= s["args"]["built"] <= s["args"]["keys"]
 

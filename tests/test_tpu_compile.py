@@ -116,18 +116,17 @@ def test_batch_cost_compiles(native, paired):
     from repro.core.hardware import PAPER_BEST
     from repro.core.layout import DataLayout
     from repro.core.workloads import googlenet
-    from repro.engine.batch_cost import (PartSpec, _batch_cost,
-                                         _candidate_grid, _prep_configs,
-                                         _prep_specs)
+    from repro.engine.batch_cost import (PartSpec, _batch_cost, _grid_size,
+                                         _prep_configs, _prep_specs)
     specs = [PartSpec(l, DataLayout("BCHW", 8), DataLayout("BHWC"))
-             for l in googlenet(1).layers
-             if _candidate_grid(l).shape[1] <= 128]
+             for l in googlenet(1).layers if _grid_size(l) <= 128]
     specs = (specs * (1024 // len(specs) + 1))[:1024]
-    lay = _prep_specs(specs, t_pad=128)
+    lay, t = _prep_specs(specs, t_pad=128)
     cfg, cons = _prep_configs([PAPER_BEST] * (1024 if paired else 1))
     with runtime.x64():
         txt = _compile(_batch_cost, _sds(native, cfg), _sds(native, lay),
-                       data_bits=cons.data_bits, psum_bits=cons.psum_bits,
+                       t_pad=t, data_bits=cons.data_bits,
+                       psum_bits=cons.psum_bits,
                        dram_row_miss=cons.dram_row_miss_cycles,
                        paired=paired)
     assert "tpu_custom_call" not in txt
